@@ -367,7 +367,6 @@ def _launch_pool(registry_root: str, workers: int):
     env = dict(os.environ)
     env["PYTHONPATH"] = str(REPO / "src") + os.pathsep + env.get(
         "PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
     proc = subprocess.Popen(
         [sys.executable, "-m", "repro.api.workers",
          "--registry", registry_root, "--workers", str(workers),

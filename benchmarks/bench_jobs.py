@@ -4,12 +4,12 @@ Three measurements over one SCALE-profile snapshot (the GO-shaped
 synthetic workload from ``configs/go_kge.py``, random embeddings — the
 axis under test is the job subsystem, not training):
 
-  * join parity — a bulk kNN join submitted through the job API must be
-    **byte-identical** (JSON bytes of every row) to a serial per-query
-    oracle driven straight at the index. The join batches query slabs
-    through the block-tiled streaming kernel; identical bytes prove the
-    batched path introduces no numeric or ordering drift. Gated at both
-    sizes.
+  * join parity — a bulk kNN join submitted through the job API must
+    return the same neighbour ids, in the same order, as a serial
+    per-query oracle driven straight at the index, with every score
+    within ``SCORE_ATOL`` of the oracle's (a 256-row slab matmul and a
+    one-row matmul may round the last float32 ulp differently). Gated at
+    both sizes.
   * p99 under fire — interactive closest-concepts p99 from threaded
     clients while a full-table bulk join is RUNNING, vs the same probe
     quiescent. The executor yields between work slabs, so the ratio
@@ -43,6 +43,7 @@ sys.path.insert(0, str(REPO / "src"))
 RESULTS = REPO / "benchmarks" / "results"
 P99_RATIO = 2.0        # interactive p99 under a running bulk job
 REJECT_MEDIAN_MS = 5.0  # HTTP 429 fast-reject median
+SCORE_ATOL = 1e-6      # join vs serial oracle: float32 rounding of scores
 K = 10
 
 
@@ -128,15 +129,19 @@ def run(fast: bool = False) -> dict:
         oracle = [[c, [[cc.identifier, cc.score]
                        for cc in idx.top_k([c], k=K)[0]]] for c in classes]
         t_oracle = time.perf_counter() - t0
-        identical = json.dumps(rows) == json.dumps(oracle)
+        identical = all(
+            r[0] == o[0] and [n[0] for n in r[1]] == [n[0] for n in o[1]]
+            and all(abs(a[1] - b[1]) <= SCORE_ATOL
+                    for a, b in zip(r[1], o[1]))
+            for r, o in zip(rows, oracle)) and len(rows) == len(oracle)
         out["join"] = {
-            "byte_identical_to_serial_oracle": bool(identical),
+            "matches_serial_oracle": bool(identical),
             "job_compute_s": st.summary["compute_s"],
             "serial_oracle_s": round(t_oracle, 4),
             "slabs": st.summary["slabs"],
         }
         print(f"  jobs[join] {join_q} queries over {n} rows: "
-              f"byte-identical={identical} "
+              f"matches oracle={identical} "
               f"(job {st.summary['compute_s']:.2f}s vs serial "
               f"{t_oracle:.2f}s, {st.summary['slabs']} slabs)")
 
@@ -257,8 +262,8 @@ def main():
     print(f"[bench_jobs] wrote {out}")
     status = "PASS" if rep["pass"] else "FAIL"
     pf = rep["p99_under_fire"]
-    print(f"[bench_jobs] {status}: join byte-identical="
-          f"{rep['join']['byte_identical_to_serial_oracle']}, "
+    print(f"[bench_jobs] {status}: join matches oracle="
+          f"{rep['join']['matches_serial_oracle']}, "
           f"interactive p99 under fire = {pf['ratio']:.2f}x quiescent "
           f"({'gated' if pf['gated'] else 'recorded'}, "
           f"floor {P99_RATIO}x), 429 median "

@@ -40,6 +40,11 @@ a jax operation (XLA backend initialization is lazy and does not survive
 ``fork``).  Each worker initializes its own backend on first kernel
 call.  ``launch.serve --workers`` therefore trains in a subprocess
 before the pool starts.
+
+One process per chip: an accelerator belongs to one process at a time,
+so a pool of more than one worker serves only where JAX's platform is the
+CPU.  ``WorkerPool.start`` asks a short-lived child which platform a
+fresh process gets (the parent stays off JAX) and refuses otherwise.
 """
 from __future__ import annotations
 
@@ -47,6 +52,7 @@ import json
 import os
 import signal
 import socket
+import subprocess
 import sys
 import tempfile
 import threading
@@ -96,6 +102,18 @@ def _atomic_write_json(path: Path, payload: Dict[str, Any]) -> None:
     tmp = path.with_name(path.name + f".tmp{os.getpid()}")
     tmp.write_text(json.dumps(payload))
     os.replace(tmp, path)
+
+
+def probe_platform(timeout_s: float = 120.0) -> str:
+    """The JAX platform a fresh process on this host gets ("cpu", "tpu",
+    ...), asked in a short-lived child so the calling process never starts
+    a backend (and never holds the chip its workers would need)."""
+    out = subprocess.run(
+        [sys.executable, "-c", "import jax; print(jax.default_backend())"],
+        capture_output=True, text=True, timeout=timeout_s)
+    if out.returncode != 0:
+        raise RuntimeError(f"JAX platform probe failed:\n{out.stderr[-2000:]}")
+    return out.stdout.split()[-1]
 
 
 # --------------------------------------------------------------------- #
@@ -265,6 +283,8 @@ def _worker_main(idx: int, registry_root: str, host: str, port: int,
     signal.signal(signal.SIGINT, signal.SIG_IGN)
 
     from ..core.registry import EmbeddingRegistry
+    from ..launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from ..core.serving import ServingEngine
     from .gateway import Gateway
     from .http import GatewayHTTPServer
@@ -457,6 +477,15 @@ class WorkerPool:
     def start(self) -> "WorkerPool":
         if self._anchor is not None:
             return self
+        if self.workers > 1:
+            platform = probe_platform()
+            if platform != "cpu":
+                raise RuntimeError(
+                    f"WorkerPool(workers={self.workers}) refused: JAX's "
+                    f"platform here is {platform!r}, and each worker would "
+                    f"need the accelerator, which only one process can "
+                    f"own. Serve with workers=1; one device-owning process "
+                    f"behind several front ends is ROADMAP item B1.")
         # warm sys.modules before forking: children then never touch the
         # import machinery (whose lock another parent thread could hold at
         # fork time). Importing jax *modules* here is fork-safe — only

@@ -9,8 +9,10 @@ interactive traffic:
 * :func:`bulk_knn_join` — all-pairs top-k neighbors for a submitted
   class list, batched through the block-tiled streaming kernel
   (``kernels.ops.topk_cosine_join``) so peak device allocation stays
-  O(query_slab · table_block + query_slab · k). Results are
-  bit-identical to a serial per-query ``top_k`` loop.
+  O(query_slab · table_block + query_slab · k). Results match a serial
+  per-query ``top_k`` loop: the same neighbour ids, scores equal up to
+  float32 rounding (a slab matmul may round the last ulp differently
+  from a one-row matmul).
 * :func:`drift_report` — per-entity neighborhood churn (Jaccard over
   top-k neighbor-id sets) between two releases, plus a ``GraphDelta``
   summary and snapshot lineage when the parsed graphs are stored.

@@ -335,8 +335,9 @@ class EmbeddingIndex:
         Walks ``rows`` in fixed ``slab``-sized query blocks through the
         slab-iterated join kernel (streaming table residency on the host
         path), yielding each block's ``List[List[ClosestConcept]]`` as
-        soon as it is scored.  Results are bit-identical to calling
-        :meth:`top_k_rows` one row at a time; the generator boundary is
+        soon as it is scored.  Results match :meth:`top_k_rows` called
+        one row at a time: the same neighbour ids, scores equal up to
+        float32 rounding of the slab matmul; the generator boundary is
         where long-running jobs publish progress, observe cancellation,
         and yield the process to interactive traffic.
         """

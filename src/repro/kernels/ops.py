@@ -1,10 +1,15 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``use_pallas`` dispatch: on this CPU container the kernels execute in
-interpret mode (numerically identical, slow); the pure-jnp reference path is
-the default for jitted production lowering on CPU and the shape source of
-truth. On a real TPU, flip REPRO_USE_PALLAS=1 (or pass use_pallas=True) and
-the same call sites run the compiled kernels with interpret=False.
+``use_pallas`` dispatch: the pure-jnp reference path is the default and
+the shape source of truth; REPRO_USE_PALLAS=1 (or use_pallas=True) routes
+the same call sites through the Pallas kernels.  Whether a kernel runs
+compiled or interpreted is decided when it is called
+(``topk_similarity.default_interpret``): compiled on a TPU, interpreted on any other backend
+(the CPU test runs).
+
+Scoring dots run at ``Precision.HIGHEST``: on a TPU a float32 matmul at
+default precision takes one bfloat16 pass, which moves cosine scores by
+~1e-3 and reorders near neighbours.  On the CPU the flag changes nothing.
 
 Streaming table residency (PR 8): when ``topk_cosine`` receives a host
 table (``np.ndarray`` / ``np.memmap``), it never puts the whole (N, d)
@@ -27,17 +32,18 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import ref
 from .kge_score import kge_score_pallas
 from .swa_attention import swa_attention_pallas
+from .topk_similarity import default_interpret as _interpret
 from .topk_similarity import topk_cosine_pallas
 
 _ENV_FLAG = os.environ.get("REPRO_USE_PALLAS", "0") == "1"
-# on CPU, pallas runs in interpret mode; on TPU, compiled
-_INTERPRET = jax.default_backend() != "tpu"
+
+#: precision of every scoring dot (see the module docstring)
+_SCORE_PRECISION = jax.lax.Precision.HIGHEST
 
 #: host-block size for the streaming top-k driver: 8192 rows × 200 dims ×
 #: 4 B ≈ 6.6 MB per transfer — large enough to amortize dispatch, small
@@ -76,7 +82,7 @@ def _stream_step_ref(q, blk, nrm, offset, limit, excl, run_s, run_i, *,
     """
     if has_norms:
         blk = blk / jnp.maximum(nrm[:, None], 1e-12)
-    s = q @ blk.T                                          # (Q, bs)
+    s = jnp.matmul(q, blk.T, precision=_SCORE_PRECISION)   # (Q, bs)
     col = offset + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(col < limit, s, ref.NEG_INF)             # tail-pad rows
     s = jnp.where(col == excl[:, None], ref.NEG_INF, s)    # self-exclusion
@@ -138,7 +144,7 @@ def _topk_stream(q_unit, e_table: np.ndarray, k: int, exclude_rows,
             bn = min(1024, max(128, rows))
             blk_s, blk_i, _ = topk_cosine_pallas(
                 q, blk, kb, exclude_rows=jnp.asarray(loc), norms=nrm,
-                block_n=bn, interpret=_INTERPRET)
+                block_n=bn)
             run_s, run_i = _stream_merge(run_s, run_i, blk_s, blk_i,
                                          jnp.int32(start), k=k_c)
             peak = max(peak, rows * d * 4 + (rows * 4 if has_norms else 0))
@@ -194,7 +200,7 @@ def topk_cosine(q_unit, e_table, k: int,
         block_n = min(1024, max(128, e_table.shape[0]))
         return topk_cosine_pallas(q_unit, e_table, k,
                                   exclude_rows=exclude_rows, norms=norms,
-                                  block_n=block_n, interpret=_INTERPRET)
+                                  block_n=block_n)
     if norms is not None:
         return ref.topk_cosine_blocked_ref(
             q_unit, e_table, k, exclude_rows=exclude_rows, norms=norms,
@@ -221,9 +227,10 @@ def topk_cosine_join(q_unit, e_table, k: int,
 
     The final partial slab is zero-padded up to ``query_block_rows``
     (pad exclusions −1) so every slab reuses one compiled step shape;
-    pad rows are dropped before yielding.  Row results are bit-identical
-    to a serial per-query :func:`topk_cosine` call: each output row of
-    the slab matmul accumulates independently of its neighbors.
+    pad rows are dropped before yielding.  Row results match a serial
+    per-query :func:`topk_cosine` call: the same neighbour ids, and scores
+    equal up to float32 rounding (a slab matmul and a one-row matmul may
+    sum the d products in a different order, so the last ulp can differ).
     """
     q = np.asarray(q_unit, np.float32)
     qn = q.shape[0]
@@ -301,7 +308,10 @@ def _sharded_topk_fn(mesh, axis: str, n_real: int, n_total: int, k: int,
     Each shard runs the existing single-device kernel contract on its
     (local_n, d) row block — global ``exclude_rows`` are translated to
     shard-local coordinates (−1 when the excluded row lives elsewhere) —
-    then a global merge top-k's the gathered shard candidates.
+    then every device gathers all shards' candidates and merges them to
+    the global top-k.  The merge runs inside the ``shard_map`` and its
+    output is replicated, so no array sharded along the merged axis ever
+    reaches ``lax.top_k`` — legal under Auto and Explicit mesh axes alike.
 
     Shard-merge invariants:
       * local fetch depth is ``min(k + n_pad, local_n)``: the zero rows
@@ -339,20 +349,23 @@ def _sharded_topk_fn(mesh, axis: str, n_real: int, n_total: int, k: int,
             s, i, _ = ref.topk_cosine_ref(q, e_loc, k_fetch, exclude_rows=loc)
         gi = i + off
         s = jnp.where(gi < n_real, s, ref.NEG_INF)
-        return s, gi
+        # shard order = global row order, so top_k's lower-position-wins
+        # tie rule keeps the lower global index, as the one-shot oracle does
+        cand_s = jax.lax.all_gather(s, axis, axis=1, tiled=True)
+        cand_i = jax.lax.all_gather(gi, axis, axis=1, tiled=True)
+        s, pos = jax.lax.top_k(cand_s, k_c)       # over (Q, shards * k_fetch)
+        return s, jnp.take_along_axis(cand_i, pos, axis=1)
 
-    # check_rep=False: pallas_call has no replication rule yet, and the
-    # outputs are explicitly sharded over ``axis`` anyway
-    mapped = shard_map(local_topk, mesh=mesh,
-                       in_specs=(P(None, None), P(axis, None), P(axis), P(None)),
-                       out_specs=(P(None, axis), P(None, axis)),
-                       check_rep=False)
+    # check_vma=False: the merged result is equal on every device, but
+    # all_gather's output is still typed as varying over ``axis``
+    mapped = jax.shard_map(
+        local_topk, mesh=mesh,
+        in_specs=(P(None, None), P(axis, None), P(axis), P(None)),
+        out_specs=(P(None, None), P(None, None)), check_vma=False)
 
     @jax.jit
     def run(q, e, nrm, excl):
-        cand_s, cand_i = mapped(q, e, nrm, excl)  # (Q, shards * k_fetch)
-        s, pos = jax.lax.top_k(cand_s, k_c)
-        i = jnp.take_along_axis(cand_i, pos, axis=1)
+        s, i = mapped(q, e, nrm, excl)
         excluded = ((excl >= 0) & (excl < n_real)).astype(jnp.int32)
         valid = jnp.minimum(k_c, n_real - excluded)
         return s, i, valid
@@ -401,7 +414,7 @@ def topk_cosine_sharded(q_unit: jnp.ndarray, e_unit: jnp.ndarray, k: int,
         nrm = jnp.ones((n_total,), jnp.float32)
         nrm = jax.device_put(nrm, NamedSharding(mesh, P(axis)))
     run = _sharded_topk_fn(mesh, axis, n_real, n_total, int(k),
-                           _use_pallas(flag=use_pallas), _INTERPRET,
+                           _use_pallas(flag=use_pallas), _interpret(),
                            has_norms)
     return run(q_unit.astype(jnp.float32), e_unit, nrm,
                jnp.asarray(exclude_rows, jnp.int32))
@@ -412,7 +425,7 @@ def kge_score(h, r, t, neg, corrupt_head, model: str = "transe_l1",
     """Fused positive+negative KGE scoring. Returns (pos (B,), neg (B, K))."""
     if _use_pallas(flag=use_pallas):
         return kge_score_pallas(h, r, t, neg, corrupt_head, model=model,
-                                interpret=_INTERPRET)
+                                interpret=_interpret())
     return ref.kge_score_ref(h, r, t, neg, corrupt_head, model=model)
 
 
@@ -429,6 +442,6 @@ def swa_attention(q, k, v, window: int, q_offset: int = 0,
         kf = k.reshape(b * hkv, skv, d)
         vf = v.reshape(b * hkv, skv, d)
         out = swa_attention_pallas(qf, kf, vf, window=window, q_offset=q_offset,
-                                   interpret=_INTERPRET)
+                                   interpret=_interpret())
         return out.reshape(b, hq, sq, d)
     return ref.swa_attention_ref(q, k, v, window=window, q_offset=q_offset)

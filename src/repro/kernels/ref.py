@@ -12,6 +12,10 @@ import jax.numpy as jnp
 
 NEG_INF = -1e30
 
+#: scoring dots keep float32 products on a TPU (default precision there
+#: is one bfloat16 pass); the CPU ignores the flag
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 # --------------------------- topk_similarity --------------------------- #
 def topk_cosine_blocked_ref(
@@ -30,8 +34,9 @@ def topk_cosine_blocked_ref(
 
     ``norms`` (optional, per-row L2) folds normalization into the score:
     ``e_table`` may then be the raw mmap rows and each block is normalized
-    with the exact float32 ops ``EmbeddingIndex.unit_rows`` uses, so scores
-    are bit-identical to pre-normalizing the full table on the host.
+    with the exact float32 ops ``EmbeddingIndex.unit_rows`` uses, so the
+    rows scored are bit-identical to pre-normalizing the full table on the
+    host.
 
     Merge tie-order matches one-shot ``lax.top_k`` on the full score
     matrix: running entries are concatenated *before* the current block's
@@ -66,7 +71,7 @@ def topk_cosine_blocked_ref(
         if nrm is not None:
             nb = jax.lax.dynamic_slice(nrm, (b * block_n,), (block_n,))
             blk = blk / jnp.maximum(nb[:, None], 1e-12)
-        s = q @ blk.T                                      # (Q, block_n)
+        s = jnp.matmul(q, blk.T, precision=HIGHEST)        # (Q, block_n)
         col = b * block_n + iota
         s = jnp.where(col < n, s, NEG_INF)                 # pad rows
         s = jnp.where(col == excl[:, None], NEG_INF, s)    # self-exclusion
@@ -97,7 +102,7 @@ def topk_cosine_ref(
     """
     n = e_unit.shape[0]
     k = min(k, n)
-    scores = q_unit @ e_unit.T
+    scores = jnp.matmul(q_unit, e_unit.T, precision=HIGHEST)
     if exclude_rows is None:
         excl = jnp.full((q_unit.shape[0],), -1, jnp.int32)
     else:

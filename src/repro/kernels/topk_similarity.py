@@ -8,8 +8,10 @@ over the table, no (Q, N) score matrix ever materialized.
 
 Grid: (N // block_n,) — sequential on TPU, so the output block is safely
 revisited and acts as the running accumulator. The merge is k rounds of
-(max, argmax, mask) over the (Q, k + block_n) candidate row — k is small
-(10 in the paper) so this stays in VREGs.
+(max, lowest-index-of-max, mask) over the running (Q, k) top-k and the
+(Q, block_n) block scores, with lane-iota selects and masked reductions
+only: the TPU lowering has no scatter, and the two widths are never
+concatenated along lanes.
 
 Serving-correctness contract (PR 1):
   * ``exclude_rows`` — per-query table row to mask out (−1 for none). The
@@ -33,6 +35,13 @@ from jax.experimental import pallas as pl
 NEG_INF = -1e30
 
 
+def default_interpret() -> bool:
+    """Pallas interpret mode: never on a TPU, always on any other backend.
+    Asked when a kernel is called, so importing this package starts no
+    backend."""
+    return jax.default_backend() != "tpu"
+
+
 def _topk_kernel(q_ref, e_ref, n_ref, x_ref, out_s_ref, out_i_ref, out_v_ref,
                  *, k: int, block_n: int, n_real: int, has_norms: bool):
     step = pl.program_id(0)
@@ -50,26 +59,38 @@ def _topk_kernel(q_ref, e_ref, n_ref, x_ref, out_s_ref, out_i_ref, out_v_ref,
     if has_norms:
         # fold the per-row L2 norms into the score: the exact float32 ops
         # EmbeddingIndex.unit_rows uses, so raw mmap rows + sidecar norms
-        # score bit-identically to a host-normalized table
+        # score like a host-normalized table
         e = e / jnp.maximum(n_ref[...], 1e-12)            # (block_n, 1) bcast
-    # MXU matmul in fp32 accumulation
-    s = jnp.dot(q, e.T, preferred_element_type=jnp.float32)   # (Q, block_n)
+    # q · eᵀ on the MXU, contracting d on both sides (no transpose);
+    # HIGHEST keeps float32 products instead of one bf16 pass
+    s = jax.lax.dot_general(q, e, (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)  # (Q, block_n)
     col = step * block_n + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(col < n_real, s, NEG_INF)                   # mask pad rows
     s = jnp.where(col == excl, NEG_INF, s)                    # self-exclusion
 
-    cand_s = jnp.concatenate([out_s_ref[...], s], axis=1)          # (Q, k+bn)
-    cand_i = jnp.concatenate([out_i_ref[...], col], axis=1)
-
-    best_s = jnp.zeros((q.shape[0], k), jnp.float32)
-    best_i = jnp.zeros((q.shape[0], k), jnp.int32)
+    # k rounds over the running top-k and this block, kept apart (no lane
+    # concat): each round takes the best score, and among equal scores the
+    # lowest global index — lax.top_k's tie order over the full row.  The
+    # winner is written to slot j by a lane-iota select and masked out by
+    # index, so no gather or scatter appears.
+    run_s = out_s_ref[...]               # (Q, k)
+    run_i = out_i_ref[...]
+    slot = jax.lax.broadcasted_iota(jnp.int32, run_s.shape, 1)
+    big = jnp.int32(jnp.iinfo(jnp.int32).max)
+    best_s = jnp.full(run_s.shape, NEG_INF, jnp.float32)
+    best_i = jnp.zeros(run_i.shape, jnp.int32)
     for j in range(k):                   # unrolled: k is small & static
-        m = jnp.max(cand_s, axis=1)                                # (Q,)
-        am = jnp.argmax(cand_s, axis=1)                            # (Q,)
-        best_s = best_s.at[:, j].set(m)
-        best_i = best_i.at[:, j].set(jnp.take_along_axis(cand_i, am[:, None], axis=1)[:, 0])
-        hit = jax.lax.broadcasted_iota(jnp.int32, cand_s.shape, 1) == am[:, None]
-        cand_s = jnp.where(hit, NEG_INF, cand_s)
+        m = jnp.maximum(jnp.max(run_s, axis=1, keepdims=True),
+                        jnp.max(s, axis=1, keepdims=True))          # (Q, 1)
+        pick = jnp.minimum(
+            jnp.min(jnp.where(run_s == m, run_i, big), axis=1, keepdims=True),
+            jnp.min(jnp.where(s == m, col, big), axis=1, keepdims=True))
+        best_s = jnp.where(slot == j, m, best_s)
+        best_i = jnp.where(slot == j, pick, best_i)
+        run_s = jnp.where(run_i == pick, NEG_INF, run_s)
+        s = jnp.where(col == pick, NEG_INF, s)
     out_s_ref[...] = best_s
     out_i_ref[...] = best_i
 
@@ -82,13 +103,16 @@ def topk_cosine_pallas(
     exclude_rows: Optional[jnp.ndarray] = None,   # (Q,) int32, -1 = none
     norms: Optional[jnp.ndarray] = None,          # (N,) per-row L2 norms
     block_n: int = 1024,
-    interpret: bool = True,   # CPU container: interpret; on TPU pass False
+    interpret: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Returns (scores (Q, k'), indices (Q, k'), valid (Q,)) with
     k' = min(k, N); rows are descending and entries past ``valid[q]`` are
     sentinel padding.  With ``norms``, ``e_unit`` may be the *raw* table
     and each streamed block is normalized in-kernel — no (N, d) unit copy
-    ever exists."""
+    ever exists.  ``interpret=None`` runs compiled on a TPU and interpreted
+    on any other backend, decided when the call is traced."""
+    if interpret is None:
+        interpret = default_interpret()
     qn, d = q_unit.shape
     n = e_unit.shape[0]
     k = min(k, n)                        # static clamp: k never exceeds N

@@ -74,6 +74,8 @@ def main():
                          "HTTP 429 + Retry-After")
     args = ap.parse_args()
 
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.api import Gateway
     from repro.core.registry import EmbeddingRegistry
     from repro.core.serving import ServingEngine

@@ -111,6 +111,8 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.workload:
         train_kge(args.workload, args.registry, args.steps, args.n_terms,
                   args.seed)

@@ -30,15 +30,6 @@ from .layers import apply_norm, dense, dense_init, mlp, mlp_init, norm_init, rop
 Params = Dict[str, Any]
 
 
-def _pvary(x: jnp.ndarray, axis: str) -> jnp.ndarray:
-    """``jax.lax.pvary`` where available (JAX >= 0.6 manual-axes typing);
-    identity otherwise — on older JAX the varying/invariant distinction
-    isn't tracked, so there is nothing to retype."""
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axis)
-    return x
-
-
 # ===================================================================== #
 # Attention block
 # ===================================================================== #
@@ -398,13 +389,6 @@ def moe_apply_shard_map(p: Params, x: jnp.ndarray, cfg: ArchConfig, mesh
     moe_dp_blocks = |data axes| (tests assert equivalence on a CPU mesh).
     """
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map as _shard_map
-        shard_map = lambda f, **kw: _shard_map(f, **kw)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-        shard_map = lambda f, mesh, in_specs, out_specs: _sm(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
 
     B, S, d = x.shape
     E0, K0 = cfg.moe.n_experts, cfg.moe.top_k
@@ -426,7 +410,7 @@ def moe_apply_shard_map(p: Params, x: jnp.ndarray, cfg: ArchConfig, mesh
         # bitwise identical, but this moves the (required) backward psum of
         # the dispatch to the TOKEN-shaped boundary dL/dxf instead of the
         # top_k-times-larger slot-shaped one (§Perf grok iteration 5).
-        xf = _pvary(xf, "model")
+        xf = jax.lax.pcast(xf, "model", to="varying")
         logits = (xf @ router).astype(jnp.float32)          # (T, E0)
         probs = jax.nn.softmax(logits, axis=-1)
         w, idx = jax.lax.top_k(probs, K0)                   # (T, K0)
@@ -479,7 +463,7 @@ def moe_apply_shard_map(p: Params, x: jnp.ndarray, cfg: ArchConfig, mesh
 
     bspec = P(dp, None, None) if (dp and B % n_dp == 0 and B >= n_dp) \
         else P(None, None, None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(bspec, P(), P("model", None, None), P("model", None, None),
                   P("model", None, None)),
@@ -498,9 +482,9 @@ def _psum_identity_bwd(y: jnp.ndarray, axis: str) -> jnp.ndarray:
         return jax.lax.psum(v, axis)
 
     f.defvjp(lambda v: (jax.lax.psum(v, axis), None),
-             # pvary: retype the (invariant) cotangent as axis-varying —
+             # pcast: retype the (invariant) cotangent as axis-varying —
              # no data movement, just the manual-axes bookkeeping.
-             lambda _, ct: (_pvary(ct, axis),))
+             lambda _, ct: (jax.lax.pcast(ct, axis, to="varying"),))
     return f(y)
 
 

@@ -1,5 +1,7 @@
 """Infrastructure: roofline HLO parser, sharding specs, data pipeline,
 optimizers, walks."""
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -185,3 +187,24 @@ def test_lr_schedules():
     i = inverse_sqrt(1.0, warmup_steps=16)
     assert float(i(16)) == pytest.approx(1.0, rel=1e-5)
     assert float(i(64)) == pytest.approx(0.5, rel=1e-4)
+
+
+def test_compile_cache_dir(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing else is set in code;
+    without it the cache sits at the fixed <repo>/.jax_cache."""
+    from repro.launch import compile_cache
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    before = {k: getattr(jax.config, k) for k in keys}
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == \
+            before["jax_compilation_cache_dir"]
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        repo_cache = Path(__file__).resolve().parents[1] / ".jax_cache"
+        assert compile_cache.enable_compile_cache() == str(repo_cache)
+        assert jax.config.jax_compilation_cache_dir == str(repo_cache)
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)

@@ -31,7 +31,10 @@ SCRIPT = textwrap.dedent("""
     from repro.models import build, get_config, runtime
     from repro.models.sharding import param_shardings
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    # Auto axes: the GSPMD path constrains with with_sharding_constraint,
+    # which jax.make_mesh's default Explicit axes refuse
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
     def run(impl):
         cfg = get_config("olmoe_1b_7b", reduced=True).with_(

@@ -56,6 +56,23 @@ def _stats(port):
     return json.loads(body)
 
 
+def test_probe_platform_asks_a_child():
+    from repro.api.workers import probe_platform
+    assert probe_platform() == "cpu"          # the suite runs JAX_PLATFORMS=cpu
+
+
+def test_pool_refuses_several_workers_off_cpu(tmp_path, monkeypatch):
+    """Each worker would need the accelerator, which one process owns:
+    the pool refuses at start instead of crash-looping its workers."""
+    from repro.api import workers
+    monkeypatch.setattr(workers, "probe_platform", lambda: "tpu")
+    pool = workers.WorkerPool(tmp_path, workers=2,
+                              state_dir=tmp_path / "state")
+    with pytest.raises(RuntimeError, match="B1"):
+        pool.start()
+    assert pool.pids() == [] and pool.port is None
+
+
 @pytest.fixture(scope="module")
 def pool(tmp_path_factory):
     root = str(tmp_path_factory.mktemp("wreg"))
