@@ -45,7 +45,7 @@ import time
 from collections import Counter
 from typing import Any, Dict, List, Optional, Tuple
 
-from ..core.metrics import LatencyHistogram
+from ..core.metrics import LatencyHistogram, span
 from ..core.serving import (BatchScheduler, SchedulerError, ServingEngine,
                             SimRequest, Ticket, TopKRequest)
 from .cache import ResultCache, canonical_payload
@@ -337,7 +337,8 @@ class Gateway:
         if timeout is None:
             timeout = self.timeout_s
         try:
-            return ticket.result(timeout=timeout)
+            with span("scheduler.wait", rid=ticket.id):
+                return ticket.result(timeout=timeout)
         except SchedulerError as e:
             raise _error_from_ticket(e) from None
         except TimeoutError:
@@ -485,10 +486,12 @@ class Gateway:
         if self.result_cache is not None:
             gw["result_cache"] = self.result_cache.stats()
         gw["jobs"] = self.jobs.stats()
+        from ..kernels import ops as kops
         return StatsResponse(
             scheduler=sched, cache=self.engine.cache_stats(), gateway=gw,
             latency={route: h.snapshot()
-                     for route, h in sorted(hists.items())})
+                     for route, h in sorted(hists.items())},
+            stream=kops.stream_counts())
 
     def _handle_versions(self, req: VersionsRequest) -> VersionsResponse:
         _req_str("ontology", req.ontology)
@@ -836,18 +839,20 @@ class Gateway:
             self.counters["by_route"][route_key] += 1
         t0 = time.perf_counter()
         try:
-            key = self._cache_key(route_key, req)
-            if key is not None:
-                hit = self.result_cache.get(key)
-                if hit is not None:
-                    return hit
-            resp = handler(req)
-            # ticket-submitting handlers (the async front end, batch
-            # staging) return the Ticket itself — the caller stores the
-            # built response once it settles
-            if key is not None and not isinstance(resp, Ticket):
-                self._cache_store(key, resp)
-            return resp
+            # the route histogram below times this span already: no counter
+            with span("gateway.route"):
+                key = self._cache_key(route_key, req)
+                if key is not None:
+                    hit = self.result_cache.get(key)
+                    if hit is not None:
+                        return hit
+                resp = handler(req)
+                # ticket-submitting handlers (the async front end, batch
+                # staging) return the Ticket itself — the caller stores
+                # the built response once it settles
+                if key is not None and not isinstance(resp, Ticket):
+                    self._cache_store(key, resp)
+                return resp
         except ApiError as e:
             self._count_error(e)
             raise

@@ -59,7 +59,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Callable, Dict, Optional, Tuple
 from urllib.parse import parse_qsl, urlsplit
 
-from ..core.metrics import LatencyHistogram
+from ..core.metrics import LatencyHistogram, span
 from .gateway import API_VERSION, Gateway, download_etag, job_etag
 from .schema import ApiError, DownloadRequest, JobResultRequest
 
@@ -182,7 +182,25 @@ class GatewayHTTPHandler(BaseHTTPRequestHandler):
         return cached[1]
 
     # ------------------------------ verbs ------------------------------ #
+    # Each verb is one ``http.request`` span: it opens once the request
+    # line and headers are read (keep-alive idle time stays outside) and
+    # closes after the buffered response is flushed to the socket.
     def do_GET(self) -> None:
+        with self._request_span():
+            self._get()
+            self.wfile.flush()
+
+    def do_POST(self) -> None:
+        with self._request_span():
+            self._post()
+            self.wfile.flush()
+
+    def _request_span(self) -> span:
+        srv = self.server
+        return span("http.request", srv.http_stats, "request_s",
+                    lock=srv._stats_lock)
+
+    def _get(self) -> None:
         self.server._count("requests")
         split = urlsplit(self.path)
         raw, dup = _params_dict(split.query)
@@ -198,7 +216,7 @@ class GatewayHTTPHandler(BaseHTTPRequestHandler):
     #: payload is a download request — a few hundred bytes)
     max_body_bytes = 1 << 20
 
-    def do_POST(self) -> None:
+    def _post(self) -> None:
         self.server._count("requests")
         split = urlsplit(self.path)
         te = self.headers.get("Transfer-Encoding")
@@ -651,19 +669,22 @@ class GatewayHTTPHandler(BaseHTTPRequestHandler):
             except (TypeError, ValueError):
                 secs = 1
             headers = (*headers, ("Retry-After", str(secs)))
-        body = json.dumps(obj).encode("utf-8")
-        self.send_response(status)
-        self.send_header("Content-Type", "application/json")
-        self.send_header("Content-Length", str(len(body)))
-        if self.close_connection:
-            # tell the client (framing-hygiene 400s drop the connection;
-            # without this header an HTTP/1.1 client would reuse it and
-            # see a reset on its next request)
-            self.send_header("Connection", "close")
-        for k, v in headers:
-            self.send_header(k, v)
-        self.end_headers()
-        self.wfile.write(body)
+        srv = self.server
+        with span("http.send", srv.http_stats, "send_s",
+                  lock=srv._stats_lock):
+            body = json.dumps(obj).encode("utf-8")
+            self.send_response(status)
+            self.send_header("Content-Type", "application/json")
+            self.send_header("Content-Length", str(len(body)))
+            if self.close_connection:
+                # tell the client (framing-hygiene 400s drop the
+                # connection; without this header an HTTP/1.1 client would
+                # reuse it and see a reset on its next request)
+                self.send_header("Connection", "close")
+            for k, v in headers:
+                self.send_header(k, v)
+            self.end_headers()
+            self.wfile.write(body)
 
     def _send_error(self, e: ApiError) -> None:
         self._send_json(e.status, e.to_wire())
@@ -715,10 +736,14 @@ class GatewayHTTPServer(ThreadingHTTPServer):
         self.stream_page_rows = stream_page_rows
         self.verbose_log = verbose_log
         self._stats_lock = threading.Lock()
-        #: transport-level counters (the gateway never sees a 304)
-        self.http_stats: Dict[str, int] = {
+        #: transport-level counters (the gateway never sees a 304);
+        #: ``request_s`` sums the ``http.request`` spans (request line
+        #: read .. response flushed), ``send_s`` the ``http.send`` spans
+        #: (JSON encoding and the write) — both in seconds
+        self.http_stats: Dict[str, Any] = {
             "requests": 0, "not_modified": 0, "streams": 0,
-            "internal_errors": 0, "max_chunk_bytes": 0}
+            "internal_errors": 0, "max_chunk_bytes": 0,
+            "request_s": 0.0, "send_s": 0.0}
         #: pre-dispatch 304 answer latency — these requests never reach
         #: the gateway's per-route histograms (satellite of the result
         #: cache work: cheap hits must still be observable)
@@ -750,7 +775,7 @@ class GatewayHTTPServer(ThreadingHTTPServer):
     def _observe_304(self, seconds: float) -> None:
         self.not_modified_latency.observe(seconds)
 
-    def http_counts(self) -> Dict[str, int]:
+    def http_counts(self) -> Dict[str, Any]:
         """Point-in-time copy of the transport counters.  The lock makes
         the snapshot consistent across counters — callers (the worker
         state dump, the pool-merged /stats) must use this instead of

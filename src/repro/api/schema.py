@@ -308,11 +308,13 @@ class StatsResponse:
     route name -> a ``LatencyHistogram.snapshot()`` (fixed log-spaced
     buckets, p50/p99 derivable — see ``repro.core.metrics``); the
     scheduler's submit->resolve histogram rides in
-    ``scheduler["latency_ms"]``."""
+    ``scheduler["latency_ms"]``; ``stream`` holds the counters of the
+    streamed top-k path (``kernels.ops.stream_stats``)."""
     scheduler: Dict[str, Any]
     cache: Dict[str, Any]
     gateway: Dict[str, Any]
     latency: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    stream: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass

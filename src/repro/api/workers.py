@@ -120,15 +120,22 @@ def probe_platform(timeout_s: float = 120.0) -> str:
 #                         cross-worker stats merge                      #
 # --------------------------------------------------------------------- #
 
+#: counters that record a largest value: they merge by max, not by sum
+_PEAK_KEYS = frozenset(("peak_block_bytes", "max_chunk_bytes"))
+
+
 def _merge_counter_dicts(a: Dict[str, Any], b: Dict[str, Any]) -> None:
-    """Add b's numeric leaves into a (in place), recursing into dicts and
-    unioning keys — the shape shared by scheduler/gateway/cache/http
-    counter blocks."""
+    """Add b's numeric leaves (int counts and float seconds) into a (in
+    place), recursing into dicts and unioning keys — the shape shared by
+    scheduler/gateway/cache/stream/http counter blocks.  Peaks
+    (``_PEAK_KEYS``) keep the largest."""
     for k, v in b.items():
         if isinstance(v, dict):
             _merge_counter_dicts(a.setdefault(k, {}), v)
         elif isinstance(v, bool) or not isinstance(v, (int, float)):
             a.setdefault(k, v)
+        elif k in _PEAK_KEYS:
+            a[k] = max(a.get(k, v), v)
         else:
             a[k] = a.get(k, 0) + v
 
@@ -145,6 +152,7 @@ def merge_stats_wires(wires: List[Dict[str, Any]]) -> Dict[str, Any]:
     sched: Dict[str, Any] = {}
     cache: Dict[str, Any] = {}
     gw: Dict[str, Any] = {}
+    stream: Dict[str, Any] = {}
     lat_routes: Dict[str, List[Dict[str, Any]]] = {}
     sched_lat: List[Dict[str, Any]] = []
     for w in wires:
@@ -155,6 +163,7 @@ def merge_stats_wires(wires: List[Dict[str, Any]]) -> Dict[str, Any]:
         _merge_counter_dicts(sched, s)
         _merge_counter_dicts(cache, w.get("cache") or {})
         _merge_counter_dicts(gw, w.get("gateway") or {})
+        _merge_counter_dicts(stream, w.get("stream") or {})
         for route, snap in (w.get("latency") or {}).items():
             lat_routes.setdefault(route, []).append(snap)
     if sched_lat:
@@ -166,6 +175,7 @@ def merge_stats_wires(wires: List[Dict[str, Any]]) -> Dict[str, Any]:
         "gateway": gw,
         "latency": {route: LatencyHistogram.merge_snapshots(snaps)
                     for route, snaps in sorted(lat_routes.items())},
+        "stream": stream,
     }
 
 

@@ -9,10 +9,19 @@ are derivable from any snapshot without keeping raw samples.
 
 Thread-safe: ``observe`` is called from gateway request threads and the
 scheduler's flush loop concurrently.
+
+``span`` names a stretch of work for two readers at once: inside a
+profiler session it emits a ``jax.profiler.TraceAnnotation``, so the
+host span shares the device trace's clock, and with ``stats``/``key`` it
+adds the elapsed seconds to that counter dict whether or not a profiler
+is on.  It never waits on the device: time lands wherever the program
+already blocks.
 """
 from __future__ import annotations
 
+import bisect
 import threading
+import time
 from typing import Any, Dict, List, Optional, Sequence
 
 #: bucket upper bounds in milliseconds: 0.01ms * 2^i, i = 0..23 (~84 s),
@@ -35,11 +44,8 @@ class LatencyHistogram:
 
     def observe(self, seconds: float) -> None:
         ms = max(seconds, 0.0) * 1e3
-        i = 0
-        for bound in BUCKET_BOUNDS_MS:
-            if ms <= bound:
-                break
-            i += 1
+        # first bound >= ms: the bucket whose upper bound holds the value
+        i = bisect.bisect_left(BUCKET_BOUNDS_MS, ms)
         with self._lock:
             self._counts[i] += 1
             self.count += 1
@@ -137,3 +143,86 @@ class LatencyHistogram:
             p = self.percentile_from(counts, q)
             out[name] = None if p is None else round(p, 4)
         return out
+
+
+#: ``jax.profiler.TraceAnnotation``, imported on first use: the pre-forking
+#: worker pool imports this module, and its parent never imports jax
+_annotation = None
+
+
+def _profiling() -> bool:
+    """Whether a profiler session is active (imports jax on first call)."""
+    global _annotation, _profiling
+    from jax.profiler import TraceAnnotation
+    _annotation = TraceAnnotation
+    _profiling = TraceAnnotation.is_enabled
+    return _profiling()
+
+
+class _Span:
+    """A span with something to record: an annotation, a counter, or both."""
+
+    __slots__ = ("name", "stats", "key", "lock", "meta", "_t0", "_ann")
+
+    def __init__(self, name: str, stats: Optional[Dict[str, Any]],
+                 key: Optional[str], lock, meta: Dict[str, Any]):
+        self.name = name
+        self.stats = stats
+        self.key = key
+        self.lock = lock
+        self.meta = meta
+        self._ann = None
+
+    def __enter__(self) -> "_Span":
+        if _profiling():
+            self._ann = _annotation(self.name, **self.meta)
+            self._ann.__enter__()
+        if self.stats is not None:
+            self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        stats = self.stats
+        if stats is not None:
+            dt = time.perf_counter() - self._t0
+            if self.lock is None:
+                stats[self.key] = stats.get(self.key, 0.0) + dt
+            else:
+                with self.lock:
+                    stats[self.key] = stats.get(self.key, 0.0) + dt
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
+        return False
+
+
+class _Quiet:
+    """The span of a block with nothing to record."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_Quiet":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_QUIET = _Quiet()
+
+
+def span(name: str, stats: Optional[Dict[str, Any]] = None,
+         key: Optional[str] = None, lock=None, **meta):
+    """``with span(name, stats, key, **meta):`` times one stretch of work.
+
+    While a profiler session is active the block is also a
+    ``TraceAnnotation(name, **meta)`` on the host trace (``meta`` such as
+    ``rid=`` rides as metadata and leaves the event name clean).  With
+    ``stats`` and ``key`` the elapsed ``perf_counter`` seconds are added
+    to ``stats[key]`` on exit, under ``lock`` when one is given.  A span
+    with neither is one shared object: the serving path allocates
+    nothing for it, so it adds no work to the garbage collector.
+    """
+    if stats is None and not _profiling():
+        return _QUIET
+    return _Span(name, stats, key, lock, meta)

@@ -68,7 +68,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 import jax.numpy as jnp
 
-from .metrics import LatencyHistogram
+from .metrics import LatencyHistogram, span
 from .registry import EmbeddingRegistry
 # canonical normalization lives with the store so publish-time sidecars
 # (sorted_labels) and serving agree; the old serving-local name survives
@@ -301,31 +301,38 @@ class EmbeddingIndex:
         if k < 1:
             raise ValueError(f"k must be >= 1, got {k}")
         rows = np.asarray(list(rows), dtype=np.int32)
-        qvec = self.unit_rows(rows)                             # (Q, d)
         excl = rows if exclude_self else np.full(len(rows), -1, np.int32)
         from ..kernels import ops as kops
+        with span("index.queries"):
+            q = jnp.asarray(self.unit_rows(rows))               # (Q, d)
+            if self.mesh is not None:
+                excl = jnp.asarray(excl)
         if self.mesh is not None:
             scores, idx, valid = kops.topk_cosine_sharded(
-                jnp.asarray(qvec), self._table_sharded, int(k),
-                exclude_rows=jnp.asarray(excl), mesh=self.mesh,
-                n_valid=self._n_real, use_pallas=self.use_pallas,
-                norms=self._norms_sharded)
+                q, self._table_sharded, int(k), exclude_rows=excl,
+                mesh=self.mesh, n_valid=self._n_real,
+                use_pallas=self.use_pallas, norms=self._norms_sharded)
         else:
             # streaming host path: the raw table (np/memmap) is walked in
             # O(block_rows) slabs, norms folded in-kernel — no device copy
             scores, idx, valid = kops.topk_cosine(
-                qvec, self.embeddings, int(k),
+                q, self.embeddings, int(k),
                 exclude_rows=excl, use_pallas=self.use_pallas,
                 norms=self.norms, block_rows=self.block_rows)
-        scores, idx, valid = np.asarray(scores), np.asarray(idx), np.asarray(valid)
+        with span("index.fetch"):
+            scores, idx = np.asarray(scores), np.asarray(idx)
+            valid = np.asarray(valid)
         out: List[List[ClosestConcept]] = []
-        for qi in range(len(rows)):
-            lst: List[ClosestConcept] = []
-            for score, j in zip(scores[qi, :valid[qi]], idx[qi, :valid[qi]]):
-                ident = self.entity_ids[int(j)]
-                lst.append(ClosestConcept(ident, self.labels[int(j)],
-                                          float(score), self.url_prefix + ident))
-            out.append(lst)
+        with span("index.decode"):
+            for qi in range(len(rows)):
+                lst: List[ClosestConcept] = []
+                for score, j in zip(scores[qi, :valid[qi]],
+                                    idx[qi, :valid[qi]]):
+                    ident = self.entity_ids[int(j)]
+                    lst.append(ClosestConcept(ident, self.labels[int(j)],
+                                              float(score),
+                                              self.url_prefix + ident))
+                out.append(lst)
         return out
 
     def knn_join_rows(self, rows: Sequence[int], k: int = 10,
@@ -940,7 +947,14 @@ class BatchScheduler:
                       # flush, zero kernel work), skipped_resolved = already
                       # resolved when the flush reached them (also skipped)
                       "rejected_overloaded": 0, "expired": 0,
-                      "skipped_resolved": 0}
+                      "skipped_resolved": 0,
+                      # time by layer: queue_wait_s sums submit -> start
+                      # of the kernel batch over the ``queued`` tickets
+                      # that reached one; batch_s sums whole top-k
+                      # batches (resolve rows .. last ticket settled),
+                      # topk_s the index call inside them
+                      "queue_wait_s": 0.0, "queued": 0, "batch_s": 0.0,
+                      "topk_s": 0.0}
         if flush_after_ms is not None:
             self.start()
 
@@ -1066,6 +1080,9 @@ class BatchScheduler:
         errors: Dict[int, str] = {}
         n_batches = n_padded = n_resolved = n_sim = 0
         n_expired = n_skipped = 0
+        # this drain's sums for the stats keys of the same names
+        times = {"queue_wait_s": 0.0, "queued": 0, "batch_s": 0.0,
+                 "topk_s": 0.0}
 
         def reject(ticket: Ticket, msg: str, code: Optional[str] = None,
                    details: Optional[Dict] = None) -> None:
@@ -1148,41 +1165,53 @@ class BatchScheduler:
                     continue
                 for start in range(0, len(items), self.max_batch):
                     chunk = items[start:start + self.max_batch]
-                    live: List[Tuple[Ticket, int]] = []     # (ticket, row)
-                    for ticket, req in chunk:
-                        # a malformed query (e.g. None) fails alone too
-                        try:
-                            row = index.resolve(req.query, fuzzy=req.fuzzy)
-                        except Exception as e:
-                            reject(ticket, f"bad query {req.query!r}: {e}",
-                                   "BAD_REQUEST")
+                    with span("scheduler.batch", times, "batch_s",
+                              rid=chunk[0][0].id):
+                        begun = time.monotonic()
+                        live: List[Tuple[Ticket, int]] = []  # (ticket, row)
+                        for ticket, req in chunk:
+                            # a malformed query (e.g. None) fails alone too
+                            try:
+                                row = index.resolve(req.query,
+                                                    fuzzy=req.fuzzy)
+                            except Exception as e:
+                                reject(ticket,
+                                       f"bad query {req.query!r}: {e}",
+                                       "BAD_REQUEST")
+                                continue
+                            if row is None:
+                                reject(ticket,
+                                       f"unknown class {req.query!r}",
+                                       "UNKNOWN_CLASS",
+                                       {"missing": [req.query]})
+                            else:
+                                live.append((ticket, row))
+                        if not live:
                             continue
-                        if row is None:
-                            reject(ticket, f"unknown class {req.query!r}",
-                                   "UNKNOWN_CLASS", {"missing": [req.query]})
-                        else:
-                            live.append((ticket, row))
-                    if not live:
-                        continue
-                    rows = [r for _, r in live]
-                    bucket = _bucket_size(len(rows), self.max_batch)
-                    pad = bucket - len(rows)
-                    try:
-                        batch_res = index.top_k_rows(rows + [rows[-1]] * pad, k)
-                    except Exception as e:
-                        code = "BAD_REQUEST" if isinstance(e, ValueError) \
-                            else None
                         for ticket, _ in live:
-                            reject(ticket, str(e), code)
-                        continue
-                    for (ticket, _), res in zip(live, batch_res):
-                        if collect:
-                            results[ticket.id] = res
-                        if ticket._resolve(res):
-                            n_resolved += 1
-                            self._observe_latency(ticket)
-                    n_batches += 1
-                    n_padded += pad
+                            times["queue_wait_s"] += begun - ticket.created
+                        times["queued"] += len(live)
+                        rows = [r for _, r in live]
+                        bucket = _bucket_size(len(rows), self.max_batch)
+                        pad = bucket - len(rows)
+                        try:
+                            with span("index.topk", times, "topk_s"):
+                                batch_res = index.top_k_rows(
+                                    rows + [rows[-1]] * pad, k)
+                        except Exception as e:
+                            code = "BAD_REQUEST" \
+                                if isinstance(e, ValueError) else None
+                            for ticket, _ in live:
+                                reject(ticket, str(e), code)
+                            continue
+                        for (ticket, _), res in zip(live, batch_res):
+                            if collect:
+                                results[ticket.id] = res
+                            if ticket._resolve(res):
+                                n_resolved += 1
+                                self._observe_latency(ticket)
+                        n_batches += 1
+                        n_padded += pad
             except Exception as e:
                 # anything unexpected rejects this queue's still-pending
                 # tickets instead of escaping into the drainer
@@ -1196,6 +1225,8 @@ class BatchScheduler:
             self.stats["resolved"] += n_resolved
             self.stats["expired"] += n_expired
             self.stats["skipped_resolved"] += n_skipped
+            for key, value in times.items():
+                self.stats[key] += value
         return results
 
     def _drain(self, queues, collect: bool = True
@@ -1310,9 +1341,11 @@ class BatchScheduler:
                         timeout = max(
                             0.0, min(self._born.values()) + period_s
                             - time.monotonic())
-                        self._cond.wait(timeout=timeout)
+                        with span("scheduler.idle"):
+                            self._cond.wait(timeout=timeout)
                     else:
-                        self._cond.wait()
+                        with span("scheduler.idle"):
+                            self._cond.wait()
                 if self._stopping or self._thread is not me:
                     return
                 n_full = 0

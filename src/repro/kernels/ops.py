@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import functools
 import os
+import threading
 from typing import Optional, Tuple
 
 import numpy as np
@@ -56,12 +57,26 @@ SHARD_BLOCK_N = 1024
 #: cumulative streaming-driver counters (reset with reset_stream_stats):
 #: ``peak_block_bytes`` is the largest single device transfer (table block
 #: + norms block) any streamed call made — the scale bench asserts it stays
-#: O(block_rows·d), i.e. no full-table private device copy ever happened
-stream_stats = {"calls": 0, "blocks": 0, "peak_block_bytes": 0}
+#: O(block_rows·d), i.e. no full-table private device copy ever happened;
+#: ``stage_s`` is host time spent copying slabs out of the table into
+#: staging buffers, ``put_s`` host time spent handing them to the device.
+#: Updated under ``_stream_lock``: the flush loop and the job executor
+#: stream concurrently.
+stream_stats = {"calls": 0, "blocks": 0, "peak_block_bytes": 0,
+                "stage_s": 0.0, "put_s": 0.0}
+_stream_lock = threading.Lock()
+
+
+def stream_counts() -> dict:
+    """A consistent copy of ``stream_stats`` (for ``/stats``)."""
+    with _stream_lock:
+        return dict(stream_stats)
 
 
 def reset_stream_stats() -> None:
-    stream_stats.update({"calls": 0, "blocks": 0, "peak_block_bytes": 0})
+    with _stream_lock:
+        stream_stats.update({"calls": 0, "blocks": 0, "peak_block_bytes": 0,
+                             "stage_s": 0.0, "put_s": 0.0})
 
 
 def _use_pallas(flag: Optional[bool]) -> bool:
@@ -123,30 +138,41 @@ def _topk_stream(q_unit, e_table: np.ndarray, k: int, exclude_rows,
     has_norms = norms is not None
     norms_np = None if norms is None else np.asarray(norms)
 
+    # a function-level import: the repro.core package imports serving,
+    # which imports this module
+    from ..core.metrics import span
+
     run_s = jnp.full((qn, k_c), ref.NEG_INF, jnp.float32)
     run_i = jnp.zeros((qn, k_c), jnp.int32)
     limit = jnp.int32(n)
     peak = 0
     n_blocks = 0
+    # host seconds of this call, added to stream_stats once at the end
+    times = {"stage_s": 0.0, "put_s": 0.0}
     for start in range(0, n, bs):
         rows = min(bs, n - start)
         if use_pallas:
             # the pallas kernel tiles internally and masks past its own
             # n_real, so hand it exactly the real rows of this slab
-            blk = jnp.asarray(np.ascontiguousarray(
-                e_table[start:start + rows], dtype=np.float32))
-            nrm = (jnp.asarray(np.ascontiguousarray(
-                norms_np[start:start + rows], dtype=np.float32))
-                if has_norms else None)
+            with span("stream.stage", times, "stage_s"):
+                blk_host = np.ascontiguousarray(
+                    e_table[start:start + rows], dtype=np.float32)
+                nrm_host = (np.ascontiguousarray(
+                    norms_np[start:start + rows], dtype=np.float32)
+                    if has_norms else None)
+            with span("stream.put", times, "put_s"):
+                blk = jnp.asarray(blk_host)
+                nrm = jnp.asarray(nrm_host) if has_norms else None
             loc = np.where((excl_np >= start) & (excl_np < start + rows),
                            excl_np - start, -1).astype(np.int32)
             kb = min(k_c, rows)
             bn = min(1024, max(128, rows))
-            blk_s, blk_i, _ = topk_cosine_pallas(
-                q, blk, kb, exclude_rows=jnp.asarray(loc), norms=nrm,
-                block_n=bn)
-            run_s, run_i = _stream_merge(run_s, run_i, blk_s, blk_i,
-                                         jnp.int32(start), k=k_c)
+            with span("stream.step"):
+                blk_s, blk_i, _ = topk_cosine_pallas(
+                    q, blk, kb, exclude_rows=jnp.asarray(loc), norms=nrm,
+                    block_n=bn)
+                run_s, run_i = _stream_merge(run_s, run_i, blk_s, blk_i,
+                                             jnp.int32(start), k=k_c)
             peak = max(peak, rows * d * 4 + (rows * 4 if has_norms else 0))
         else:
             # fixed-size slab (tail zero-padded) → one jitted step shape.
@@ -154,21 +180,28 @@ def _topk_stream(q_unit, e_table: np.ndarray, k: int, exclude_rows,
             # jnp.asarray can adopt an aligned numpy buffer zero-copy on
             # CPU, so a reused scratch array would be rewritten under the
             # previous (async-dispatched) step and merge the wrong rows.
-            blk_host = np.zeros((bs, d), np.float32)
-            blk_host[:rows] = e_table[start:start + rows]
-            nrm_host = np.ones((bs,), np.float32)
-            if has_norms:
-                nrm_host[:rows] = norms_np[start:start + rows]
-            run_s, run_i = _stream_step_ref(
-                q, jnp.asarray(blk_host), jnp.asarray(nrm_host),
-                jnp.int32(start), limit, excl, run_s, run_i,
-                k=k_c, has_norms=has_norms)
+            with span("stream.stage", times, "stage_s"):
+                blk_host = np.zeros((bs, d), np.float32)
+                blk_host[:rows] = e_table[start:start + rows]
+                nrm_host = np.ones((bs,), np.float32)
+                if has_norms:
+                    nrm_host[:rows] = norms_np[start:start + rows]
+            with span("stream.put", times, "put_s"):
+                blk = jnp.asarray(blk_host)
+                nrm = jnp.asarray(nrm_host)
+            with span("stream.step"):
+                run_s, run_i = _stream_step_ref(
+                    q, blk, nrm, jnp.int32(start), limit, excl, run_s, run_i,
+                    k=k_c, has_norms=has_norms)
             peak = max(peak, bs * d * 4 + bs * 4)
         n_blocks += 1
-    stream_stats["calls"] += 1
-    stream_stats["blocks"] += n_blocks
-    stream_stats["peak_block_bytes"] = max(
-        stream_stats["peak_block_bytes"], peak)
+    with _stream_lock:
+        stream_stats["calls"] += 1
+        stream_stats["blocks"] += n_blocks
+        stream_stats["peak_block_bytes"] = max(
+            stream_stats["peak_block_bytes"], peak)
+        stream_stats["stage_s"] += times["stage_s"]
+        stream_stats["put_s"] += times["put_s"]
     excluded = ((excl_np >= 0) & (excl_np < n)).astype(np.int32)
     valid = jnp.asarray(np.minimum(k_c, n - excluded).astype(np.int32))
     return run_s, run_i, valid

@@ -126,6 +126,10 @@ class KGETrainer:
         log: Optional[Callable[[Dict[str, Any]], None]] = None,
     ) -> Tuple[Params, Any, Dict[str, Any]]:
         """Train for ``epochs`` (paper default 100) or an explicit ``steps``."""
+        # a function-level import: the repro.core package imports the
+        # updater, which imports this module
+        from ..core.metrics import span
+
         cfg = self.cfg
         if params is None:
             params, opt_state = self.init()
@@ -138,9 +142,14 @@ class KGETrainer:
         losses = []
         t0 = time.perf_counter()
         for i in range(total_steps):
-            key, sub = jax.random.split(key)
-            batch = jnp.asarray(next(it))
-            params, opt_state, loss = self._step(params, opt_state, batch, sub)
+            # trace-only spans: no counter, one annotation each under a
+            # profiler and two cheap calls without one
+            with span("train.batch"):
+                key, sub = jax.random.split(key)
+                batch = jnp.asarray(next(it))
+            with span("train.step"):
+                params, opt_state, loss = self._step(params, opt_state,
+                                                     batch, sub)
             if i % cfg.log_every == 0 or i == total_steps - 1:
                 l = float(loss)
                 losses.append((i, l))

@@ -78,3 +78,22 @@ def test_concurrent_observe_loses_nothing():
     snap = h.snapshot()
     assert snap["count"] == n_threads * per
     assert sum(snap["bucket_counts"]) == n_threads * per
+
+
+def test_bucket_lookup_matches_a_linear_scan_at_every_bound():
+    """``observe`` bisects the bounds; each value lands where a scan for
+    the first bound >= the value puts it, exact bounds included."""
+    def scan(ms):
+        for i, bound in enumerate(BUCKET_BOUNDS_MS):
+            if ms <= bound:
+                return i
+        return len(BUCKET_BOUNDS_MS)
+
+    values = [0.0, 1e-9, 1e9]
+    for b in BUCKET_BOUNDS_MS:
+        values += [b, b * (1 - 1e-12), b * (1 + 1e-12), b * 1.5]
+    for ms in values:
+        h = LatencyHistogram()
+        h.observe(ms / 1e3)
+        counts = h.snapshot()["bucket_counts"]
+        assert counts.index(1) == scan(max(ms / 1e3, 0.0) * 1e3), ms
