@@ -309,7 +309,8 @@ class StatsResponse:
     buckets, p50/p99 derivable — see ``repro.core.metrics``); the
     scheduler's submit->resolve histogram rides in
     ``scheduler["latency_ms"]``; ``stream`` holds the counters of the
-    streamed top-k path (``kernels.ops.stream_stats``)."""
+    streamed top-k path and the launches served from a resident table
+    (``kernels.ops.stream_stats``)."""
     scheduler: Dict[str, Any]
     cache: Dict[str, Any]
     gateway: Dict[str, Any]

@@ -53,6 +53,14 @@ Architecture (PR 1 hardening — see ROADMAP.md "Serving architecture"):
                        devices and top-k runs through the sharded
                        kernel path (``kernels.ops.topk_cosine_sharded``):
                        local top-k per shard + global merge.
+
+  Device residency     on one device, the engine keeps an index's raw rows
+                       and norms in HBM when they fit a per-index
+                       allowance (half the device's reported memory over
+                       the LRU's capacity); a top-k batch is then one
+                       launch (``kernels.ops.topk_cosine_resident``).
+                       Larger tables, and devices that report no memory
+                       stats, stream from the host.
 """
 from __future__ import annotations
 
@@ -66,6 +74,7 @@ from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from .metrics import LatencyHistogram, span
@@ -125,15 +134,18 @@ class EmbeddingIndex:
     memmap view) or are computed once here, and unit rows are produced on
     demand by ``unit_rows``.
 
-    Scale-oblivious device residency (PR 8): top-k streams the host table
-    through the kernel in fixed ``block_rows`` slabs with the norms folded
-    into the in-kernel score (``kernels.ops.topk_cosine``), so there is no
-    full-table device copy and *no* (N, d) unit array on either side —
-    peak device allocation is O(block_rows·d + Q·k) regardless of N.  Host
-    memory stays in the shared page cache, so worker processes serving the
-    same snapshot pay for the table once.  With a multi-device mesh the
-    raw rows + norms are laid out sharded instead (the residency there
-    *is* the sharding) and each shard normalizes its blocks in-kernel.
+    Device residency, one of three: by default top-k streams the host
+    table through the kernel in fixed ``block_rows`` slabs with the norms
+    folded into the in-kernel score (``kernels.ops.topk_cosine``), so there
+    is no full-table device copy and *no* (N, d) unit array on either side
+    — peak device allocation is O(block_rows·d + Q·k) regardless of N.
+    Host memory stays in the shared page cache, so worker processes
+    serving the same snapshot pay for the table once.  After
+    ``_make_resident`` (which ``ServingEngine`` calls when the table fits
+    the device) the raw rows + norms sit on the device and each batch is
+    one launch of the same slab step.  With a multi-device mesh the raw
+    rows + norms are laid out sharded instead (the residency there *is*
+    the sharding) and each shard normalizes its blocks in-kernel.
     """
 
     def __init__(self, entity_ids: Sequence[str], labels: Sequence[str],
@@ -171,6 +183,8 @@ class EmbeddingIndex:
         else:
             self._table_sharded = self._norms_sharded = None
             self._n_real = emb.shape[0]
+        #: the single-device resident copy (``_make_resident``), or None
+        self._resident = None
         self._id_to_row = {i: r for r, i in enumerate(self.entity_ids)}
         self._label_to_row: Dict[str, int] = {}
         for r, lbl in enumerate(self.labels):
@@ -208,11 +222,20 @@ class EmbeddingIndex:
         ``unit_rows`` or the streaming/sharded kernel paths."""
         return self.unit_rows(slice(None))
 
+    def _make_resident(self) -> None:
+        """Put the raw rows and norms on the default device once, padded
+        to a multiple of the slab; top-k then runs one launch a batch."""
+        from ..kernels import ops as kops
+        self._resident = kops.ResidentTable(self.embeddings, self.norms,
+                                            self.block_rows)
+
     def device_table_bytes(self) -> int:
         """Bytes of *table* data pinned on devices by this index: 0 on the
-        streaming host path (the scale invariant the bench asserts — only
-        transient O(block) slabs ever land on device), table + norms bytes
-        when mesh-sharded (residency there is the sharding itself)."""
+        streaming host path (only transient O(block) slabs ever land on
+        device), the padded table + norms when resident on one device or
+        mesh-sharded (residency there is the sharding itself)."""
+        if self._resident is not None:
+            return self._resident.nbytes
         if self._table_sharded is None:
             return 0
         return int(self._table_sharded.nbytes + self._norms_sharded.nbytes)
@@ -296,32 +319,44 @@ class EmbeddingIndex:
         Self-exclusion and k>N clamping happen inside the kernel (per-query
         exclude operand + valid-count output), so results contain exactly
         ``min(k, N - exclude_self)`` real entries — no sentinel rows, no
-        over-fetch-then-filter.
+        over-fetch-then-filter.  A resident index puts only the row ids:
+        the launch slices out and normalises the queries on device.
         """
         if k < 1:
             raise ValueError(f"k must be >= 1, got {k}")
         rows = np.asarray(list(rows), dtype=np.int32)
         excl = rows if exclude_self else np.full(len(rows), -1, np.int32)
         from ..kernels import ops as kops
-        with span("index.queries"):
-            q = jnp.asarray(self.unit_rows(rows))               # (Q, d)
-            if self.mesh is not None:
-                excl = jnp.asarray(excl)
-        if self.mesh is not None:
-            scores, idx, valid = kops.topk_cosine_sharded(
-                q, self._table_sharded, int(k), exclude_rows=excl,
-                mesh=self.mesh, n_valid=self._n_real,
-                use_pallas=self.use_pallas, norms=self._norms_sharded)
+        if self._resident is not None:
+            with span("index.queries"):
+                rows_dev = jax.device_put(rows)
+            with span("index.launch"):
+                launched = kops.topk_cosine_resident(
+                    self._resident, rows_dev, int(k),
+                    exclude_self=exclude_self)
+            with span("index.fetch"):
+                scores, idx = jax.device_get(launched)
+            valid = kops.valid_counts(excl, self._n_real, int(k))
         else:
-            # streaming host path: the raw table (np/memmap) is walked in
-            # O(block_rows) slabs, norms folded in-kernel — no device copy
-            scores, idx, valid = kops.topk_cosine(
-                q, self.embeddings, int(k),
-                exclude_rows=excl, use_pallas=self.use_pallas,
-                norms=self.norms, block_rows=self.block_rows)
-        with span("index.fetch"):
-            scores, idx = np.asarray(scores), np.asarray(idx)
-            valid = np.asarray(valid)
+            with span("index.queries"):
+                q = jnp.asarray(self.unit_rows(rows))           # (Q, d)
+                if self.mesh is not None:
+                    excl = jnp.asarray(excl)
+            if self.mesh is not None:
+                scores, idx, valid = kops.topk_cosine_sharded(
+                    q, self._table_sharded, int(k), exclude_rows=excl,
+                    mesh=self.mesh, n_valid=self._n_real,
+                    use_pallas=self.use_pallas, norms=self._norms_sharded)
+            else:
+                # streaming host path: the raw table (np/memmap) is walked
+                # in O(block_rows) slabs, norms folded in-kernel
+                scores, idx, valid = kops.topk_cosine(
+                    q, self.embeddings, int(k),
+                    exclude_rows=excl, use_pallas=self.use_pallas,
+                    norms=self.norms, block_rows=self.block_rows)
+            with span("index.fetch"):
+                scores, idx = np.asarray(scores), np.asarray(idx)
+                valid = np.asarray(valid)
         out: List[List[ClosestConcept]] = []
         with span("index.decode"):
             for qi in range(len(rows)):
@@ -341,7 +376,8 @@ class EmbeddingIndex:
 
         Walks ``rows`` in fixed ``slab``-sized query blocks through the
         slab-iterated join kernel (streaming table residency on the host
-        path), yielding each block's ``List[List[ClosestConcept]]`` as
+        path; a resident or sharded table serves each block in place),
+        yielding each block's ``List[List[ClosestConcept]]`` as
         soon as it is scored.  Results match :meth:`top_k_rows` called
         one row at a time: the same neighbour ids, scores equal up to
         float32 rounding of the slab matmul; the generator boundary is
@@ -353,9 +389,9 @@ class EmbeddingIndex:
         rows = np.asarray(list(rows), dtype=np.int32)
         excl = rows if exclude_self else np.full(len(rows), -1, np.int32)
         from ..kernels import ops as kops
-        if self.mesh is not None:
-            # sharded tables stay device-resident: reuse the sharded
-            # batch path per slab (same merge contract, same results)
+        if self.mesh is not None or self._resident is not None:
+            # device-resident tables (sharded or on one device): reuse
+            # the batch path per slab (same merge contract, same results)
             for start in range(0, len(rows), slab):
                 part = rows[start:start + slab]
                 yield start, self.top_k_rows(
@@ -500,8 +536,22 @@ class ServingEngine:
             idx = EmbeddingIndex(ids, labels, table, norms=norms,
                                  use_pallas=self.use_pallas, mesh=self.mesh,
                                  sorted_labels=meta.get("sorted_labels"))
+            if idx.mesh is None and idx.nbytes <= self._resident_allowance():
+                idx._make_resident()
             self.cache.put(key, idx)
         return idx
+
+    def _resident_allowance(self) -> int:
+        """Device bytes one single-device index may keep resident: half
+        of what the device reports it can hold, shared by the LRU's
+        capacity.  -1 (nothing fits) where the device reports no memory
+        stats, as the CPU backend does, or where the Pallas backend is
+        selected (its kernel is launched per streamed slab)."""
+        from ..kernels import ops as kops
+        limit = kops.device_bytes_limit()
+        if limit is None or kops._use_pallas(self.use_pallas):
+            return -1
+        return limit // 2 // self.cache.capacity
 
     def invalidate(self, ontology: str, new_version: Optional[str] = None
                    ) -> Optional[str]:

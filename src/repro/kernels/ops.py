@@ -11,16 +11,27 @@ Scoring dots run at ``Precision.HIGHEST``: on a TPU a float32 matmul at
 default precision takes one bfloat16 pass, which moves cosine scores by
 ~1e-3 and reorders near neighbours.  On the CPU the flag changes nothing.
 
-Streaming table residency (PR 8): when ``topk_cosine`` receives a host
-table (``np.ndarray`` / ``np.memmap``), it never puts the whole (N, d)
-array on device.  The host loop walks the table in fixed ``block_rows``
-slabs — each slab is transferred, scored (with the sidecar ``norms``
-folded into the kernel, so no unit copy exists on *either* side), and
-merged into a running (Q, k) top-k.  Peak device allocation is
-O(block_rows·d + Q·k) regardless of N; ``stream_stats`` records it so the
-scale bench can assert the bound.  A jnp-array table keeps the original
-device-resident single-launch path (the mesh-sharded path also stays
-device-resident — residency there is the sharding itself).
+Table residency.  Three layouts serve one top-k contract:
+
+* **streamed** — ``topk_cosine`` on a host table (``np.ndarray`` /
+  ``np.memmap``) never puts the whole (N, d) array on device.  The host
+  loop walks the table in fixed ``block_rows`` slabs — each slab is
+  transferred, scored (with the sidecar ``norms`` folded into the kernel,
+  so no unit copy exists on *either* side), and merged into a running
+  (Q, k) top-k.  Peak device allocation is O(block_rows·d + Q·k)
+  regardless of N; ``stream_stats`` records it so the scale bench can
+  assert the bound.
+* **resident** — ``ResidentTable`` puts the raw rows and norms on one
+  device once, padded to a multiple of the slab, and
+  ``topk_cosine_resident`` serves a batch in one launch: the query rows
+  are sliced out and normalised on device, then a ``fori_loop`` runs the
+  streamed path's slab step over the resident slabs.  Same step, same
+  shapes, same tie order; no table bytes cross the host link per batch.
+* **sharded** — on a multi-device mesh the table lives sharded in HBM
+  (``shard_table_raw`` / ``topk_cosine_sharded``).
+
+A jnp-array table passed to ``topk_cosine`` keeps the single-launch
+device path.
 """
 from __future__ import annotations
 
@@ -59,11 +70,13 @@ SHARD_BLOCK_N = 1024
 #: + norms block) any streamed call made — the scale bench asserts it stays
 #: O(block_rows·d), i.e. no full-table private device copy ever happened;
 #: ``stage_s`` is host time spent copying slabs out of the table into
-#: staging buffers, ``put_s`` host time spent handing them to the device.
+#: staging buffers, ``put_s`` host time spent handing them to the device;
+#: ``resident_calls`` counts top-k launches served from a resident table
+#: (they add nothing to the streaming counters).
 #: Updated under ``_stream_lock``: the flush loop and the job executor
 #: stream concurrently.
 stream_stats = {"calls": 0, "blocks": 0, "peak_block_bytes": 0,
-                "stage_s": 0.0, "put_s": 0.0}
+                "stage_s": 0.0, "put_s": 0.0, "resident_calls": 0}
 _stream_lock = threading.Lock()
 
 
@@ -76,16 +89,16 @@ def stream_counts() -> dict:
 def reset_stream_stats() -> None:
     with _stream_lock:
         stream_stats.update({"calls": 0, "blocks": 0, "peak_block_bytes": 0,
-                             "stage_s": 0.0, "put_s": 0.0})
+                             "stage_s": 0.0, "put_s": 0.0,
+                             "resident_calls": 0})
 
 
 def _use_pallas(flag: Optional[bool]) -> bool:
     return _ENV_FLAG if flag is None else flag
 
 
-@functools.partial(jax.jit, static_argnames=("k", "has_norms"))
-def _stream_step_ref(q, blk, nrm, offset, limit, excl, run_s, run_i, *,
-                     k: int, has_norms: bool):
+def _stream_step(q, blk, nrm, offset, limit, excl, run_s, run_i, *,
+                 k: int, has_norms: bool):
     """Score one (block_rows, d) slab and merge it into the running top-k.
 
     ``offset``/``limit`` are traced scalars (block start, real table rows),
@@ -105,6 +118,10 @@ def _stream_step_ref(q, blk, nrm, offset, limit, excl, run_s, run_i, *,
     cand_i = jnp.concatenate([run_i, col], axis=1)
     s2, pos = jax.lax.top_k(cand_s, k)
     return s2, jnp.take_along_axis(cand_i, pos, axis=1)
+
+
+#: the streamed path's per-slab launch
+_stream_step_ref = jax.jit(_stream_step, static_argnames=("k", "has_norms"))
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -202,9 +219,93 @@ def _topk_stream(q_unit, e_table: np.ndarray, k: int, exclude_rows,
             stream_stats["peak_block_bytes"], peak)
         stream_stats["stage_s"] += times["stage_s"]
         stream_stats["put_s"] += times["put_s"]
-    excluded = ((excl_np >= 0) & (excl_np < n)).astype(np.int32)
-    valid = jnp.asarray(np.minimum(k_c, n - excluded).astype(np.int32))
-    return run_s, run_i, valid
+    return run_s, run_i, jnp.asarray(valid_counts(excl_np, n, k_c))
+
+
+def valid_counts(exclude_rows, n: int, k: int) -> np.ndarray:
+    """Real entries per query row, on the host: ``min(k, n)``, one fewer
+    where the query's excluded row lies in the table."""
+    excl = np.asarray(exclude_rows)
+    excluded = ((excl >= 0) & (excl < n)).astype(np.int32)
+    return np.minimum(min(int(k), n), n - excluded).astype(np.int32)
+
+
+def device_bytes_limit() -> Optional[int]:
+    """Bytes the default device reports it can hold
+    (``memory_stats()["bytes_limit"]``); None where the backend reports
+    no memory stats, as the CPU backend does."""
+    stats = jax.local_devices()[0].memory_stats()
+    if not stats or "bytes_limit" not in stats:
+        return None
+    return int(stats["bytes_limit"])
+
+
+class ResidentTable:
+    """A raw (N, d) table and its per-row norms held on the default
+    device, padded once to a multiple of the slab (zero rows, norms of 1,
+    as :func:`shard_table_raw` pads) so no launch copies the table."""
+
+    def __init__(self, e_table, norms, block_rows: Optional[int] = None):
+        n, d = e_table.shape
+        self.n = int(n)
+        #: the slab the streamed path would walk this table in
+        self.block_rows = max(1, min(int(block_rows or STREAM_BLOCK_ROWS),
+                                     self.n))
+        n_pad = -self.n % self.block_rows
+        table = np.zeros((self.n + n_pad, d), np.float32)
+        table[:self.n] = e_table
+        nrm = np.ones((self.n + n_pad,), np.float32)
+        nrm[:self.n] = norms
+        self.table = jax.device_put(table)
+        self.norms = jax.device_put(nrm)
+        self.limit = jax.device_put(np.int32(self.n))
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.table.nbytes + self.norms.nbytes)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("k", "block_rows", "exclude_self"))
+def _resident_topk(table, norms, limit, rows, *, k: int, block_rows: int,
+                   exclude_self: bool):
+    """One launch over a resident table (:class:`ResidentTable`): slice
+    out and normalise the query rows, then walk the table's slabs with
+    the streamed path's step (:func:`_stream_step`) in a ``fori_loop`` —
+    the same arithmetic on the same shapes, slabs in ascending order, so
+    ties resolve to the lower index exactly as they do streamed."""
+    qn = rows.shape[0]
+    # one slice per query, unrolled: under a gather or a fori_loop the
+    # TPU compiler stages the whole table into VMEM on every launch
+    q = jnp.concatenate([jax.lax.dynamic_slice_in_dim(table, rows[i], 1)
+                         for i in range(qn)])
+    q = q / jnp.maximum(norms[rows][:, None], 1e-12)
+    excl = rows if exclude_self else jnp.full_like(rows, -1)
+    run = (jnp.full((qn, k), ref.NEG_INF, jnp.float32),
+           jnp.zeros((qn, k), jnp.int32))
+
+    def body(b, carry):
+        start = b * block_rows
+        blk = jax.lax.dynamic_slice_in_dim(table, start, block_rows)
+        nrm = jax.lax.dynamic_slice_in_dim(norms, start, block_rows)
+        return _stream_step(q, blk, nrm, start, limit, excl, *carry, k=k,
+                            has_norms=True)
+
+    return jax.lax.fori_loop(0, table.shape[0] // block_rows, body, run)
+
+
+def topk_cosine_resident(res: ResidentTable, rows, k: int,
+                         exclude_self: bool = True
+                         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Top-k of the table's own ``rows`` (an int32 device array) against
+    the resident table: (scores, indices), each (Q, min(k, N)), on the
+    device.  Entries past :func:`valid_counts` are sentinel padding."""
+    out = _resident_topk(res.table, res.norms, res.limit, rows,
+                         k=min(int(k), res.n), block_rows=res.block_rows,
+                         exclude_self=bool(exclude_self))
+    with _stream_lock:
+        stream_stats["resident_calls"] += 1
+    return out
 
 
 def topk_cosine(q_unit, e_table, k: int,

@@ -14,6 +14,7 @@ without a chip).  All of these stay in this one file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -89,6 +90,29 @@ def test_stream_step_ref_compiles(one_chip):
         _spec((q, K), jnp.float32, one_chip),
         _spec((q, K), jnp.int32, one_chip)).compile()
     assert compiled.memory_analysis() is not None
+
+
+@pytest.mark.parametrize("q", [1, 2, 4, 8, 16, 32])
+def test_resident_topk_compiles(one_chip, q):
+    """The one-launch batch over a resident GO table: 40,000 rows padded
+    to five slabs, at each scheduler bucket up to 32 queries."""
+    from repro.kernels.ops import STREAM_BLOCK_ROWS, _resident_topk
+
+    n_pad = -(-GO_N // STREAM_BLOCK_ROWS) * STREAM_BLOCK_ROWS
+    compiled = _resident_topk.lower(
+        _spec((n_pad, D), jnp.float32, one_chip),
+        _spec((n_pad,), jnp.float32, one_chip),
+        _spec((), jnp.int32, one_chip),
+        _spec((q,), jnp.int32, one_chip),
+        k=K, block_rows=STREAM_BLOCK_ROWS, exclude_self=True).compile()
+    text = compiled.as_text()
+    assert f"f32[{n_pad},{D}]" in text
+    # no launch copies the table, nor a slab of it, to another layout or
+    # memory space
+    assert not re.search(
+        rf"= f32\[({D},{n_pad}|{n_pad},{D}|{D},{STREAM_BLOCK_ROWS}|"
+        rf"{STREAM_BLOCK_ROWS},{D})\]\S* (copy|copy-start|transpose)\(",
+        text)
 
 
 def test_transe_train_step_compiles(one_chip):
