@@ -55,20 +55,19 @@ def serve_readings(cell, cfg, mix, fleet, seed: int, seconds: float):
             "p99_ms": rec["client"]["p99_ms"]}
 
 
-def train_readings(cfg, mix, seed: int):
+def train_readings(cfg, mix, model_ref, seed: int):
     from benchmarks.chip import checks, reference, train_cell
 
     tr = train_cell.Training(cfg, mix, seed)
     prog = tr.first_steps()
     tc = dict(cfg["train"])
     args = (tr.triples, tr.step_seeds, tr.n_entities)
-    params0 = reference.transe_init(tr.init_seed, tr.n_entities,
-                                    tr.n_relations, int(cfg["dim"]))
+    params0 = model_ref.init(tr.init_seed, tr.n_entities, tr.n_relations,
+                             int(cfg["dim"]))
     tr.free()
-    ref = reference.transe_reference(params0, *args, tc)
-    low = reference.transe_reference(params0, *args, tc,
-                                     rnd=reference.bfloat16)
-    half = reference.transe_reference(
+    ref = model_ref.reference(params0, *args, tc)
+    low = model_ref.reference(params0, *args, tc, rnd=reference.bfloat16)
+    half = model_ref.reference(
         params0, *args, {**tc, "batch_size": tc["batch_size"] // 2})
     return {"program": checks.compare_training(prog, ref, params0),
             "control": checks.compare_training(low, ref, params0),
@@ -86,6 +85,7 @@ def main(argv=None) -> int:
     cfg = spec.config(cell["config"])
     mix = spec.traffic(cell["traffic"])
     serving = mix["kind"] == "topk_http"
+    model_ref = None if serving else spec.train_reference(cfg["model"])
     fleet = client.Fleet(int(mix["client_processes"]),
                          int(mix["client_threads"])) if serving else None
     try:
@@ -93,7 +93,7 @@ def main(argv=None) -> int:
         devices = run.open_chips(int(cell["chips"]))
         for seed in (int(s) for s in args.seeds.split(",")):
             out = (serve_readings(cell, cfg, mix, fleet, seed, args.seconds)
-                   if serving else train_readings(cfg, mix, seed))
+                   if serving else train_readings(cfg, mix, model_ref, seed))
             print(json.dumps({"cell": args.workload, "seed": seed, **out,
                               "device": devices[0].device_kind}), flush=True)
     finally:

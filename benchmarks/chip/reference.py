@@ -1,4 +1,5 @@
-"""Plain references of the two configurations' semantics.
+"""Plain references: cosine top-k, and the parts of KGE training that
+no model changes.
 
 Nothing here imports the program.  The references take the benchmark's
 own inputs (the seeded table, the seeded ontology's triples) and rebuild
@@ -6,16 +7,16 @@ everything else themselves:
 
 * cosine top-k: a float64 brute force over the raw table and its norms,
   each query's own row excluded, ties to the lower row;
-* TransE training: the L1 score, uniform head/tail corruption, the
-  margin-ranking loss, its gradient by hand, Adam and the unit-ball
-  constraint on entity rows, in float64 on the host.  The initial
-  parameters, the shuffled batches and the negatives are redrawn from
-  the same seeds with ``jax.random`` and NumPy, the way the published
-  algorithm draws them.
+* KGE training, shared by every model's ``references/<model>.py``: the
+  shuffled first batch of an epoch and the uniform head/tail negatives,
+  redrawn from the same seeds with NumPy and ``jax.random`` the way the
+  published algorithm draws them; a signed scatter of row gradients; and
+  Adam, in float64 on the host.  A model's file adds its score, loss,
+  gradient by hand and constraint.
 
 The controls compute the same in the precision below the configuration's
-float32: ``topk_control`` scores with three bfloat16 passes, and
-``transe_reference(..., rnd=bfloat16)`` rounds every intermediate to
+float32: ``topk_control`` scores with three bfloat16 passes, and a
+model's ``reference(..., rnd=bfloat16)`` rounds every intermediate to
 bfloat16.
 """
 from __future__ import annotations
@@ -128,8 +129,8 @@ def topk_control(table: np.ndarray, query_rows: Sequence[int], k: int,
     return out
 
 
-# ------------------------------ TransE --------------------------------- #
-def _float64(x: np.ndarray) -> np.ndarray:
+# --------------------------- KGE training ------------------------------ #
+def float64(x: np.ndarray) -> np.ndarray:
     return np.asarray(x, np.float64)
 
 
@@ -138,30 +139,6 @@ def bfloat16(x: np.ndarray) -> np.ndarray:
     import ml_dtypes
     return np.asarray(x, np.float32).astype(ml_dtypes.bfloat16).astype(
         np.float64)
-
-
-def transe_init(seed: int, n_entities: int, n_relations: int,
-                dim: int) -> Dict[str, np.ndarray]:
-    """Xavier-uniform entity and relation rows U(-6/sqrt(d), 6/sqrt(d)),
-    relation rows scaled to unit L2 norm, drawn from ``jax.random.key(seed)``
-    split into (entity, relation) keys."""
-    import jax
-    import jax.numpy as jnp
-
-    @jax.jit
-    def draw(key):
-        ke, kr = jax.random.split(key)
-        bound = 6.0 / jnp.sqrt(jnp.asarray(dim, jnp.float32))
-        ent = jax.random.uniform(ke, (n_entities, dim), jnp.float32,
-                                 -bound, bound)
-        rel = jax.random.uniform(kr, (n_relations, dim), jnp.float32,
-                                 -bound, bound)
-        return ent, rel
-
-    ent, rel = jax.device_get(draw(jax.random.key(seed)))
-    rel = _float64(rel)
-    rel = rel / (np.linalg.norm(rel, axis=-1, keepdims=True) + 1e-12)
-    return {"entity": _float64(ent), "relation": rel}
 
 
 def first_batch(triples: np.ndarray, batch: int, seed: int) -> np.ndarray:
@@ -206,8 +183,8 @@ def negatives(seed: int, batch: np.ndarray, n_entities: int,
     return h, r, t
 
 
-def _signed_scatter(n_rows: int, plus: np.ndarray, minus, vals: np.ndarray
-                    ) -> np.ndarray:
+def signed_scatter(n_rows: int, plus: np.ndarray, minus, vals: np.ndarray
+                   ) -> np.ndarray:
     """(n_rows, d): each row of ``vals`` added at ``plus`` and, where
     ``minus`` is given, subtracted at ``minus``."""
     import scipy.sparse as sp
@@ -222,48 +199,26 @@ def _signed_scatter(n_rows: int, plus: np.ndarray, minus, vals: np.ndarray
     return sp.csr_matrix((data, (rows, cols)), shape=(n_rows, m)) @ vals
 
 
-def transe_step(params: Dict[str, np.ndarray], adam: Dict[str, Dict],
-                batch: np.ndarray, negs: Tuple, step: int, lr: float,
-                margin: float = 1.0, b1: float = 0.9, b2: float = 0.999,
-                eps: float = 1e-8,
-                rnd: Callable[[np.ndarray], np.ndarray] = _float64
-                ) -> Tuple[Dict, Dict, float, Dict[str, np.ndarray]]:
-    """One step: (params, adam moments, loss, gradients)."""
-    ent, rel = params["entity"], params["relation"]
-    h, r, t = batch[:, 0], batch[:, 1], batch[:, 2]
-    nh, nr, nt = (a.reshape(-1) for a in negs)
-    b, k = negs[0].shape
-    d = ent.shape[1]
-    x = rnd(rnd(ent[h] + rel[r]) - ent[t])                    # (B, d)
-    xn = ent[nh]                                              # (B*K, d)
-    xn += rel[nr]
-    xn = rnd(xn)
-    xn -= ent[nt]
-    xn = rnd(xn)
-    pos = rnd(-np.abs(x).sum(-1))
-    neg = rnd(-np.abs(xn).sum(-1)).reshape(b, k)
-    hinge = margin + neg - pos[:, None]
-    active = (hinge > 0).astype(np.float64)
-    loss = float(rnd(np.maximum(hinge, 0.0).mean()))
-    d_neg = (active / (b * k)).reshape(-1, 1)                 # dL/dneg
-    d_pos = -active.sum(1, keepdims=True) / (b * k)           # dL/dpos
-    gx = rnd(np.sign(x) * -d_pos)                             # dL/dx
-    gxn = np.sign(xn)
-    gxn *= -d_neg
-    gxn = rnd(gxn)
-    grads = {
-        "entity": rnd(_signed_scatter(len(ent), h, t, gx)
-                      + _signed_scatter(len(ent), nh, nt, gxn)),
-        "relation": rnd(_signed_scatter(len(rel), r, None, gx)
-                        + _signed_scatter(len(rel), nr, None, gxn)),
-    }
-    new_params, new_adam = {}, {}
+def adam_state(params: Dict[str, np.ndarray]) -> Dict[str, Dict]:
+    """Adam's first and second moments of every leaf, at zero."""
+    return {k: {"mu": np.zeros_like(v), "nu": np.zeros_like(v)}
+            for k, v in params.items()}
+
+
+def adam(params: Dict[str, np.ndarray], grads: Dict[str, np.ndarray],
+         state: Dict[str, Dict], step: int, lr: float,
+         rnd: Callable[[np.ndarray], np.ndarray] = float64,
+         b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8
+         ) -> Tuple[Dict[str, np.ndarray], Dict[str, Dict]]:
+    """One bias-corrected Adam update of every leaf at ``step`` (from 1),
+    each moment and the update rounded by ``rnd``: (params, state)."""
+    new_params, new_state = {}, {}
     for name, p in params.items():
         g = grads[name]
-        m = adam[name]["mu"] * b1
+        m = state[name]["mu"] * b1
         m += (1 - b1) * g
         m = rnd(m)
-        v = adam[name]["nu"] * b2
+        v = state[name]["nu"] * b2
         gg = g * g
         gg *= 1 - b2
         v += gg
@@ -277,31 +232,5 @@ def transe_step(params: Dict[str, np.ndarray], adam: Dict[str, Dict],
         upd *= -lr
         upd += p
         new_params[name] = rnd(upd)
-        new_adam[name] = {"mu": m, "nu": v}
-    e = new_params["entity"]
-    norm = np.linalg.norm(e, axis=-1, keepdims=True)
-    new_params["entity"] = rnd(e / np.maximum(norm, 1.0))
-    return new_params, new_adam, loss, grads
-
-
-def transe_reference(params0: Dict[str, np.ndarray], triples: np.ndarray,
-                     step_seeds: Sequence[int], n_entities: int, cfg: Dict,
-                     rnd: Callable[[np.ndarray], np.ndarray] = _float64
-                     ) -> Dict[str, object]:
-    """Follow the program's first ``len(step_seeds)`` steps, one fresh
-    epoch shuffle and key per step.  Returns each step's loss, the first
-    step's gradients and the parameters after the last step."""
-    params = {k: rnd(v) for k, v in params0.items()}
-    adam = {k: {"mu": np.zeros_like(v), "nu": np.zeros_like(v)}
-            for k, v in params.items()}
-    losses, first_grads = [], None
-    for i, seed in enumerate(step_seeds):
-        batch = first_batch(triples, cfg["batch_size"], seed)
-        negs = negatives(seed, batch, n_entities, cfg["num_negs"])
-        params, adam, loss, grads = transe_step(
-            params, adam, batch, negs, i + 1, cfg["lr"],
-            margin=cfg["margin"], rnd=rnd)
-        losses.append(loss)
-        if first_grads is None:
-            first_grads = grads
-    return {"losses": losses, "grads": first_grads, "params": params}
+        new_state[name] = {"mu": m, "nu": v}
+    return new_params, new_state

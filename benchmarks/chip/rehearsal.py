@@ -1,13 +1,14 @@
 """Rehearse cells on the CPU at a tiny size, with the look for a chip
-skipped: the tests' fixtures, and the four-device rehearsal of the
-sharded cell, which needs a process of its own.
+skipped: the tests' fixtures, and the four-device rehearsal of a
+four-chip cell, which needs a process of its own.
 
     XLA_FLAGS=--xla_force_host_platform_device_count=4 JAX_PLATFORMS=cpu \
         python3 -m benchmarks.chip.rehearsal <tmpdir> <cell> [<fault>]
 
 ``build_tree`` writes a checkout-shaped directory holding the real metric
-readers and tiny copies of the cells (the real mixes and configurations
-with their sizes cut) and points ``spec`` at it.
+readers and model references and a tiny copy of every cell of
+BENCHMARK.json under its own name: its own mix, configuration and limits,
+with the sizes cut.  ``main`` points ``spec`` at it.
 """
 from __future__ import annotations
 
@@ -15,21 +16,25 @@ import json
 import shutil
 import sys
 from pathlib import Path
-from typing import Any, Dict
+from typing import Any, Dict, List, Optional
 
 HERE = Path(__file__).resolve().parent
 for _p in (HERE.parents[1] / "src", HERE.parents[1]):
     if str(_p) not in sys.path:
         sys.path.insert(0, str(_p))
 
-#: tiny cell -> (configuration, mix, chips, workload overrides)
-TINY = {
-    "serve-zipf": ("go40k-transe", "zipf-topk", 1, {"rate_per_s": 60}),
-    "serve-uniform": ("go40k-transe", "uniform-topk", 1, {"rate_per_s": 60}),
-    "serve-sharded": ("go40k-transe", "uniform-topk-sharded", 4,
-                      {"rate_per_s": 60}),
-    "train": ("go40k-transe", "fit-chunks", 1, {}),
-}
+#: the rate of every tiny serving cell, in requests per second
+TINY_RATE = 60
+
+
+def cells(chips: int, kind: Optional[str] = None) -> List[str]:
+    """BENCHMARK.json's cells on ``chips`` chips, of one traffic ``kind``
+    or of any."""
+    from benchmarks.chip import spec
+
+    bench = spec.benchmark()
+    return [w["name"] for w in bench["workloads"] if w["chips"] == chips
+            and kind in (None, spec.traffic(w["traffic"])["kind"])]
 
 
 def _write(path: Path, obj: Any) -> None:
@@ -37,54 +42,39 @@ def _write(path: Path, obj: Any) -> None:
     path.write_text(json.dumps(obj))
 
 
+def _read(kind: str, name: str) -> Dict[str, Any]:
+    return json.loads((HERE / kind / f"{name}.json").read_text())
+
+
 def build_tree(root: Path) -> Path:
-    """Write the tiny tree under ``root``; returns its benchmark dir."""
+    """Write a tiny copy of every cell of BENCHMARK.json under ``root``,
+    each under its own name and with its own limits; returns the tree's
+    benchmark dir."""
     from benchmarks.chip import spec
 
     here = root / "benchmarks" / "chip"
-    shutil.copytree(HERE / "metrics", here / "metrics", dirs_exist_ok=True)
+    for kind in ("metrics", "references"):
+        shutil.copytree(HERE / kind, here / kind, dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     peaks = json.loads((HERE / "peaks.json").read_text())
     peaks["devices"].append({**peaks["devices"][0], "name": "rehearsal",
                              "device_kinds": ["cpu"]})
     _write(here / "peaks.json", peaks)
-    bench = json.loads((spec.ROOT / "BENCHMARK.json").read_text())
-    bench["workloads"] = []
-    limits = _limits()
-    for name, (config, mix, chips, extra) in TINY.items():
-        cfg = json.loads((HERE / "configs" / f"{config}.json").read_text())
+    shutil.copy(spec.ROOT / "BENCHMARK.json", root)
+    for w in spec.benchmark()["workloads"]:
+        cfg = _read("configs", w["config"])
         cfg.update(n_terms=300, dim=16)
-        _write(here / "configs" / f"{config}.json", cfg)
-        m = json.loads((HERE / "traffic" / f"{mix}.json").read_text())
+        _write(here / "configs" / f"{w['config']}.json", cfg)
+        m = _read("traffic", w["traffic"])
         m.update(client_processes=1, client_threads=3, check_sample=40,
                  warm_cache_requests=min(64, m.get("warm_cache_requests", 0)),
                  chunk_steps=3, trace_seconds=0.5)
-        _write(here / "traffic" / f"{mix}.json", m)
-        _write(here / "workloads" / f"{name}.json",
-               {"config": config, "traffic": mix, "chips": chips,
-                "limits": limits[m["kind"]], **extra})
-        bench["workloads"].append({"name": name, "config": config,
-                                   "traffic": mix, "chips": chips,
-                                   "why": "tiny"})
-    for group in ("end_to_end", "per_layer"):
-        for m in bench[group]:
-            if "workloads" in m:
-                train = any("train" in w for w in m["workloads"])
-                m["workloads"] = (["train"] if train else
-                                  ["serve-zipf", "serve-uniform",
-                                   "serve-sharded"])
-    _write(root / "BENCHMARK.json", bench)
+        _write(here / "traffic" / f"{w['traffic']}.json", m)
+        wl = _read("workloads", w["name"])
+        if "rate_per_s" in wl:
+            wl["rate_per_s"] = TINY_RATE
+        _write(here / "workloads" / f"{w['name']}.json", wl)
     return here
-
-
-def _limits() -> Dict[str, Dict[str, float]]:
-    """Each kind's limits, as the real cells set them."""
-    out = {}
-    for path in sorted((HERE / "workloads").glob("*.json")):
-        wl = json.loads(path.read_text())
-        kind = json.loads((HERE / "traffic" / f"{wl['traffic']}.json")
-                          .read_text())["kind"]
-        out.setdefault(kind, wl["limits"])
-    return out
 
 
 def rehearse(cell: str, seed: int = 12345678901, seconds: float = 1.0,

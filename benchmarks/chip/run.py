@@ -167,8 +167,8 @@ def serve(args, cell, cfg, mix, fleet, devices, peaks, compiles):
     return record, e2e, numbers, mem, rec["requests"], c["failed"]
 
 
-def train(args, cell, cfg, mix, devices, peaks, compiles):
-    from benchmarks.chip import reference, train_cell
+def train(args, cell, cfg, mix, model_ref, devices, peaks, compiles):
+    from benchmarks.chip import train_cell
 
     tr = train_cell.Training(cfg, mix, args.seed)
     log("set-up: ontology, trainer and state built")
@@ -197,9 +197,9 @@ def train(args, cell, cfg, mix, devices, peaks, compiles):
     log(hoststats.describe(host, gcs.summary(),
                            [("parent", t, g) for t, g in beat.stalls], t0))
     log(f"compilations inside the window: {in_window}")
-    tc = cfg["train"]
-    params0 = reference.transe_init(init_seed, n_ent, n_rel, int(cfg["dim"]))
-    ref = reference.transe_reference(params0, triples, step_seeds, n_ent, tc)
+    tc, dim = cfg["train"], int(cfg["dim"])
+    params0 = model_ref.init(init_seed, n_ent, n_rel, dim)
+    ref = model_ref.reference(params0, triples, step_seeds, n_ent, tc)
     numbers = checks.compare_training(prog, ref, params0)
     log("reference: checked steps followed")
     rate = win["triples"] / win["elapsed_s"]
@@ -207,7 +207,8 @@ def train(args, cell, cfg, mix, devices, peaks, compiles):
               "chips": len(devices), "seconds": args.seconds,
               "train": {**win, "triples_per_s": rate, "trace_steps": tsteps},
               "trace": trace, "setup_s": setup_s,
-              "work": {"dim": int(cfg["dim"]), "num_negs": int(tc["num_negs"])}}
+              "work": {"model": cfg["model"], "flops_per_triple":
+                       model_ref.flops_per_triple(dim, int(tc["num_negs"]))}}
     return record, {"train_triples_per_s": rate}, numbers, mem, \
         win["steps"], 0
 
@@ -228,6 +229,8 @@ def run(args: argparse.Namespace, platform: str = "tpu") -> dict:
     cell = spec.cell(bench, args.workload)
     cfg = spec.config(cell["config"])
     mix = spec.traffic(cell["traffic"])
+    model_ref = (spec.train_reference(cfg["model"])
+                 if mix["kind"] == "kge_train" else None)
     fleet = None
     if mix["kind"] == "topk_http":
         fleet = client.Fleet(int(mix["client_processes"]),
@@ -243,7 +246,8 @@ def run(args: argparse.Namespace, platform: str = "tpu") -> dict:
             out = serve(args, cell, cfg, mix, fleet, devices, peaks,
                         compiles)
         elif mix["kind"] == "kge_train":
-            out = train(args, cell, cfg, mix, devices, peaks, compiles)
+            out = train(args, cell, cfg, mix, model_ref, devices, peaks,
+                        compiles)
         else:
             raise spec.SpecError(f"unknown traffic kind {mix['kind']!r}")
     finally:

@@ -46,8 +46,9 @@ class GatewaySpans:
 
 
 def make_table(n: int, dim: int, seed: int) -> np.ndarray:
-    """The served float32 table, made on the device in one jitted call:
-    TransE's initial law, U(-6/sqrt(d), 6/sqrt(d))."""
+    """The served float32 table, made on the device in one jitted call
+    from the Xavier-uniform initial law U(-6/sqrt(d), 6/sqrt(d)), whatever
+    the configuration's ``model``."""
     import jax
     import jax.numpy as jnp
 

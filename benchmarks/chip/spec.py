@@ -1,4 +1,5 @@
-"""Find configurations, mixes, cells, metrics and peaks by name.
+"""Find configurations, mixes, cells, metrics, model references and
+peaks by name.
 
 Nothing here imports JAX: the client fleet's processes import this
 module too, and they never touch the chip.
@@ -9,6 +10,7 @@ import importlib.util
 import json
 import zlib
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
@@ -83,17 +85,30 @@ def per_layer(bench: Dict[str, Any], name: str) -> List[Dict[str, Any]]:
     return out
 
 
-def metric_reader(name: str) -> Callable[[Dict[str, Any]], Optional[float]]:
-    """``metrics/<name>.py``'s ``read(record)``."""
-    path = HERE / "metrics" / f"{name}.py"
+def _module(kind: str, name: str) -> ModuleType:
+    """``<kind>/<name>.py``, loaded afresh from ``HERE``."""
+    path = HERE / kind / f"{name}.py"
     if not path.is_file():
-        raise SpecError(f"no metric reader {path}")
-    mod_name = "chipbench_metric_" + "".join(
+        raise SpecError(f"no {kind} file for {name!r} ({path})")
+    mod_name = f"chipbench_{kind}_" + "".join(
         c if c.isalnum() else "_" for c in name)
     spec = importlib.util.spec_from_file_location(mod_name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str) -> Callable[[Dict[str, Any]], Optional[float]]:
+    """``metrics/<name>.py``'s ``read(record)``."""
+    return _module("metrics", name).read
+
+
+def train_reference(model: str) -> ModuleType:
+    """``references/<model>.py``, the plain reference of a configuration's
+    ``model``: ``init(seed, n_entities, n_relations, dim)``,
+    ``reference(params0, triples, step_seeds, n_entities, train_cfg,
+    rnd=...)`` and ``flops_per_triple(dim, num_negs)``."""
+    return _module("references", model)
 
 
 def peaks(device_kind: str) -> Dict[str, Any]:

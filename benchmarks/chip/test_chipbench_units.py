@@ -56,7 +56,16 @@ def test_roofline_names_its_bound():
 
 def test_transe_flops_per_triple():
     # forward 4d per scored triple, 1 + 32 per positive, backward 2x
-    assert work.transe_flops_per_triple(200, 32) == 3 * 4 * 200 * 33
+    flops = spec.train_reference("transe").flops_per_triple
+    assert flops(200, 32) == 3 * 4 * 200 * 33
+
+
+def test_train_mfu_reads_the_models_flops_from_the_record():
+    rec = {"train": {"triples_per_s": 327_460.0}, "chips": 1,
+           "work": {"model": "transe", "flops_per_triple": 79_200.0},
+           "peaks": spec.peaks("TPU v5 lite")}
+    got = spec.metric_reader("train_mfu")(rec)
+    assert got == pytest.approx(100 * 79_200 * 327_460 / 197e12)
 
 
 def test_peaks_unknown_device_is_an_error():
@@ -128,6 +137,9 @@ def test_every_cell_config_mix_and_metric_is_found_by_name():
         assert "setup_s" in e2e and len(e2e) >= 2
         layer = spec.per_layer(bench, w["name"])
         assert layer and all(m["moves"] in e2e for m in layer)
+        if spec.traffic(cell["traffic"])["kind"] == "kge_train":
+            model = spec.train_reference(spec.config(cell["config"])["model"])
+            assert callable(model.init) and callable(model.reference)
     for m in bench["per_layer"]:
         assert callable(spec.metric_reader(m["name"]))
     for c in bench["configs"]:
@@ -136,6 +148,8 @@ def test_every_cell_config_mix_and_metric_is_found_by_name():
         spec.workload("no-such-cell")
     with pytest.raises(spec.SpecError):
         spec.metric_reader("no_such_metric")
+    with pytest.raises(spec.SpecError, match="references/no_such_model.py"):
+        spec.train_reference("no_such_model")
 
 
 def test_metric_readers_find_nothing_in_an_empty_record():

@@ -33,7 +33,9 @@ class Training:
         self.n_entities = kg.num_entities
         self.n_relations = kg.num_relations
         model = make_model(cfg["model"], kg.num_entities, kg.num_relations,
-                           dim=int(cfg["dim"]))
+                           dim=int(cfg["dim"]), loss=tr["loss"],
+                           margin=float(tr["margin"]),
+                           p_norm=int(tr["p_norm"]))
         self.base = TrainConfig(batch_size=int(tr["batch_size"]),
                                 num_negs=int(tr["num_negs"]),
                                 optimizer=tr["optimizer"], lr=float(tr["lr"]))

@@ -2,11 +2,11 @@
 
 These count what the algorithm requires, not what an implementation
 does: a top-k batch scores every query against every row once and reads
-the table and its norms once; a TransE step runs the forward and backward
-pass of the margin loss over each positive and its negatives.  The
-scoring dots run at ``Precision.HIGHEST``, about six bfloat16 passes, and
-these counts still hold them against the bfloat16 peak: the compute
-ceiling of those dots is about a sixth of what a share counts.
+the table and its norms once.  A training step's flops per triple belong
+to its model and live in ``references/<model>.py``.  The scoring dots
+run at ``Precision.HIGHEST``, about six bfloat16 passes, and these
+counts still hold them against the bfloat16 peak: the compute ceiling of
+those dots is about a sixth of what a share counts.
 """
 from __future__ import annotations
 
@@ -19,16 +19,6 @@ def topk_batches(q_rows_total: int, batches: int, n_rows: int,
     query rows between them, padding rows included."""
     flops = 2.0 * q_rows_total * n_rows * dim
     return flops, float(batches) * n_rows * (dim + 1) * 4
-
-
-def transe_flops_per_triple(dim: int, num_negs: int) -> float:
-    """Forward + backward flops of the L1 TransE margin loss per positive
-    triple, optimizer excluded.  Forward per scored triple: h + r - t
-    (2d), |x| (d) and the sum (d); the hinge is O(1).  Backward is
-    counted as twice the forward.  Each positive brings ``num_negs``
-    negatives."""
-    forward = 4.0 * dim * (1 + num_negs)
-    return 3.0 * forward
 
 
 def roofline_s(flops: float, nbytes: float, peaks: Dict, chips: int) -> Tuple[float, str]:
